@@ -20,6 +20,9 @@ past the lane's used count are skipped entirely with pl.when.
 Block shapes: the (group x page_size) score tile wants page_size to be
 a multiple of 128 on real TPUs (lane alignment; q group is padded to a
 sublane multiple by ops.py).  Interpret mode (CPU CI) takes any shape.
+The int32 position pages travel as (P, 1, ps) with (1, 1, ps) blocks:
+Mosaic needs a block's last two dims divisible by (8, 128) or equal to
+the array's, and a unit middle axis satisfies that at any page size.
 """
 
 from __future__ import annotations
@@ -55,7 +58,7 @@ def _kernel(table_ref, qpos_ref, nused_ref, q_ref, k_ref, v_ref, pos_ref,
         q = q_ref[0].astype(jnp.float32)            # (G, hd)
         k = k_ref[0, 0].astype(jnp.float32)         # (ps, hd)
         v = v_ref[0, 0].astype(jnp.float32)
-        kpos = pos_ref[0]                           # (ps,) i32
+        kpos = pos_ref[0, 0]                        # (ps,) i32
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         qp = qpos_ref[lane]
@@ -109,9 +112,9 @@ def paged_attention_kernel(q, k_pages, v_pages, pos_pages, page_table,
             pl.BlockSpec((1, 1, ps, hd),
                          lambda bh, j, t, qp, nu, hkv=hkv:
                          (t[bh // hkv, j], bh % hkv, 0, 0)),
-            pl.BlockSpec((1, ps),
+            pl.BlockSpec((1, 1, ps),
                          lambda bh, j, t, qp, nu, hkv=hkv:
-                         (t[bh // hkv, j], 0)),
+                         (t[bh // hkv, j], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, g, hd),
                                lambda bh, j, t, qp, nu: (bh, 0, 0)),
@@ -129,5 +132,5 @@ def paged_attention_kernel(q, k_pages, v_pages, pos_pages, page_table,
         out_shape=jax.ShapeDtypeStruct((b * hkv, g, hd), q.dtype),
         interpret=interpret,
     )(page_table, q_pos.astype(jnp.int32), n_used.astype(jnp.int32),
-      qf, k_pages, v_pages, pos_pages)
+      qf, k_pages, v_pages, pos_pages[:, None, :])
     return out.reshape(b, hkv, g, hd)

@@ -32,7 +32,10 @@ Masking contract (matches models/attention.py `attn_prefill_chunk`):
 Pages past ``ceil(chunk_start / ps)`` are skipped with pl.when.  Block
 shapes: the (C*G, ps) score tile wants ps and the chunk-key axis padded
 to 128 on real TPUs and C*G to a sublane multiple (ops.py pads);
-interpret mode (CPU CI) takes any shape.
+interpret mode (CPU CI) takes any shape.  The int32 position arrays
+(pool pages, query rows, chunk keys) travel with a unit middle axis,
+(N, 1, W) in (1, 1, W) blocks, so their last two block dims equal the
+array's as Mosaic requires.
 """
 
 from __future__ import annotations
@@ -88,8 +91,8 @@ def _kernel(table_ref, start_ref, nhist_ref, q_ref, qpos_ref, k_ref, v_ref,
 
     @pl.when((j < nj - 1) & (j < nhist_ref[lane]))
     def _history_page():
-        kpos = pos_ref[0]                                 # (ps,) i32
-        qp = qpos_ref[0]                                  # (C,) i32
+        kpos = pos_ref[0, 0]                              # (ps,) i32
+        qp = qpos_ref[0, 0]                               # (C,) i32
         valid = (kpos[None, :] >= 0) \
             & (kpos[None, :] < start_ref[lane]) \
             & (kpos[None, :] <= qp[:, None])
@@ -101,8 +104,8 @@ def _kernel(table_ref, start_ref, nhist_ref, q_ref, qpos_ref, k_ref, v_ref,
 
     @pl.when(j == nj - 1)
     def _in_chunk():
-        ckpos = cpos_ref[0]                               # (Cp,) i32
-        qp = qpos_ref[0]                                  # (C,) i32
+        ckpos = cpos_ref[0, 0]                            # (Cp,) i32
+        qp = qpos_ref[0, 0]                               # (C,) i32
         valid = (ckpos[None, :] >= 0) & (qp[:, None] >= 0) \
             & (ckpos[None, :] <= qp[:, None])
         if window is not None:
@@ -145,9 +148,9 @@ def paged_prefill_kernel(q, q_pos, k_pages, v_pages, pos_pages, page_table,
         in_specs=[
             pl.BlockSpec((1, cg, hd),
                          lambda bh, j, t, st, nh: (bh, 0, 0)),
-            pl.BlockSpec((1, c),
+            pl.BlockSpec((1, 1, c),
                          lambda bh, j, t, st, nh, hkv=hkv:
-                         (bh // hkv, 0)),
+                         (bh // hkv, 0, 0)),
             pl.BlockSpec((1, 1, ps, hd),
                          lambda bh, j, t, st, nh, hkv=hkv, maxp=maxp:
                          (t[bh // hkv, jnp.minimum(j, maxp - 1)],
@@ -156,16 +159,16 @@ def paged_prefill_kernel(q, q_pos, k_pages, v_pages, pos_pages, page_table,
                          lambda bh, j, t, st, nh, hkv=hkv, maxp=maxp:
                          (t[bh // hkv, jnp.minimum(j, maxp - 1)],
                           bh % hkv, 0, 0)),
-            pl.BlockSpec((1, ps),
+            pl.BlockSpec((1, 1, ps),
                          lambda bh, j, t, st, nh, maxp=maxp, hkv=hkv:
-                         (t[bh // hkv, jnp.minimum(j, maxp - 1)], 0)),
+                         (t[bh // hkv, jnp.minimum(j, maxp - 1)], 0, 0)),
             pl.BlockSpec((1, cp, hd),
                          lambda bh, j, t, st, nh: (bh, 0, 0)),
             pl.BlockSpec((1, cp, hd),
                          lambda bh, j, t, st, nh: (bh, 0, 0)),
-            pl.BlockSpec((1, cp),
+            pl.BlockSpec((1, 1, cp),
                          lambda bh, j, t, st, nh, hkv=hkv:
-                         (bh // hkv, 0)),
+                         (bh // hkv, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, cg, hd),
                                lambda bh, j, t, st, nh: (bh, 0, 0)),
@@ -183,6 +186,6 @@ def paged_prefill_kernel(q, q_pos, k_pages, v_pages, pos_pages, page_table,
         out_shape=jax.ShapeDtypeStruct((b * hkv, cg, hd), q.dtype),
         interpret=interpret,
     )(page_table, chunk_start.astype(jnp.int32), n_hist.astype(jnp.int32),
-      qf, q_pos.astype(jnp.int32), k_pages, v_pages, pos_pages,
-      ckf, cvf, c_pos.astype(jnp.int32))
+      qf, q_pos.astype(jnp.int32)[:, None, :], k_pages, v_pages,
+      pos_pages[:, None, :], ckf, cvf, c_pos.astype(jnp.int32)[:, None, :])
     return out.reshape(b, hkv, cg, hd)

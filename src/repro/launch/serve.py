@@ -62,6 +62,7 @@ import numpy as np
 
 from repro import strategy
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as M
 from repro.models.param import materialize
 from repro.serving.engine import Engine
@@ -714,6 +715,7 @@ def main() -> None:
                          "(escalations park past their deadlines; the "
                          "chaos baseline the governor is gated against)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.lanes is None:
         args.lanes = args.batch
     if args.cascade_lanes is None:

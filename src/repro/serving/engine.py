@@ -41,6 +41,7 @@ admission-order invariance.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -231,7 +232,11 @@ def make_token_step(params, cfg: ModelConfig, strategies, *,
     bool, sid (B,) i32[, kv][, states][, chunk]) -> (next_tok,
     new_caches, served_node, seg_batch, seg_policy[, states])`` — seg_*
     are int32 scalars counting this token's launched segments and
-    per-lane probed segments.
+    per-lane probed segments.  It is a `functools.partial` binding
+    ``params`` as the first argument of the (jitted) program in
+    ``.func``, so the weights enter it as arguments; ``params`` may be
+    `jax.eval_shape` structs to lower ``.func`` without materializing
+    them.
     """
     import contextlib
 
@@ -253,8 +258,8 @@ def make_token_step(params, cfg: ModelConfig, strategies, *,
                     f"{type(s).__name__} is persistent — its cross-token "
                     "state cannot double as a mid-token walk handoff")
 
-    def step(tok, caches, pos, occupied, sid, kv=None, states_in=None,
-             chunk=None, walk=None):
+    def step(params, tok, caches, pos, occupied, sid, kv=None,
+             states_in=None, chunk=None, walk=None):
         b = tok.shape[0]
         x = params["embed"]["table"][tok][:, None, :]
         if resume_walk:
@@ -371,11 +376,13 @@ def make_token_step(params, cfg: ModelConfig, strategies, *,
             out = out + ((active, best_logits),)
         return out
 
-    if not jit:
-        return step
-    if donate is None:
-        donate = jax.default_backend() != "cpu"
-    return jax.jit(step, donate_argnums=(1,) if donate else ())
+    if jit:
+        if donate is None:
+            donate = jax.default_backend() != "cpu"
+        step = jax.jit(step, donate_argnums=(2,) if donate else ())
+    # the weights are an argument of the program, not constants baked
+    # into it: one compiled step, one copy of the weights on the device
+    return functools.partial(step, params)
 
 
 class Engine:

@@ -177,21 +177,15 @@ def write_events(tracer, path: str, *, faults=None) -> dict[str, Any]:
 def profiler_capture(logdir: str | None):
     """Optional ``jax.profiler`` capture around the serve loop for
     kernel-level attribution against `bench_roofline.py`.  A no-op
-    when ``logdir`` is falsy, and degrades to a no-op if the profiler
-    backend is unavailable in this build."""
+    when ``logdir`` is falsy.  When it is given, an error starting or
+    stopping the trace propagates: a run that was asked for a trace
+    does not succeed without one."""
     if not logdir:
         yield
         return
     import jax
-    try:
-        jax.profiler.start_trace(logdir)
-    except Exception:                     # pragma: no cover - env specific
-        yield
-        return
+    jax.profiler.start_trace(logdir)
     try:
         yield
     finally:
-        try:
-            jax.profiler.stop_trace()
-        except Exception:                 # pragma: no cover - env specific
-            pass
+        jax.profiler.stop_trace()

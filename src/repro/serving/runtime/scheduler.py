@@ -47,6 +47,8 @@ admission order cannot change what any request generates
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -285,7 +287,7 @@ class EngineStepper:
         else:
             self.pool = None
 
-            def admit_fn(caches, tok, pos, prompt, lane):
+            def admit_fn(params, caches, tok, pos, prompt, lane):
                 logits, pc, _, npos = M.prefill(params, cfg,
                                                 {"tokens": prompt},
                                                 cache_len)
@@ -298,16 +300,19 @@ class EngineStepper:
                 return (caches, tok.at[lane].set(t0),
                         pos.at[lane].set(npos[0].astype(jnp.int32)))
 
-        self._admit = jax.jit(admit_fn) if jit else admit_fn
+        # weights enter the admit program as an argument, never as
+        # constants (see make_token_step)
+        self._admit = functools.partial(
+            jax.jit(admit_fn) if jit else admit_fn, params)
         self.alloc()
 
     # ---- paged device programs ----------------------------------------
 
     def _make_paged_admit(self):
-        params, cfg, prompt_len = self.params, self.cfg, self.prompt_len
+        cfg, prompt_len = self.cfg, self.prompt_len
 
-        def admit_fn(caches, tok, pos, prompt, lane, dest_page, dest_slot,
-                     pos_vals, new_pages):
+        def admit_fn(params, caches, tok, pos, prompt, lane, dest_page,
+                     dest_slot, pos_vals, new_pages):
             # prefill at cache_len == prompt_len: the ring layout is the
             # identity (slot t <- position t), so the per-token page
             # scatter below reads positions straight through

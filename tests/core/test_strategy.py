@@ -12,8 +12,21 @@ import pytest
 from repro import strategy
 from repro.core import skip_dp, traces
 from repro.core.line_dp import solve_line
-from repro.core.markov import MarkovChain, sample_chain
+from repro.core.markov import MarkovChain
 from repro.core.support import Support
+
+
+def _sample_chain_np(rng, p0, trans, t):
+    """(t, n) bin trajectories drawn with numpy's RNG, so the pinned
+    traces do not move with JAX's PRNG implementation."""
+    def draw(probs):                     # one categorical draw per row
+        cum = np.cumsum(probs, axis=-1)
+        u = rng.random(probs.shape[0]) * cum[:, -1]
+        return np.minimum((u[:, None] >= cum).sum(-1), probs.shape[-1] - 1)
+    bins = [draw(np.broadcast_to(p0, (t, p0.shape[0])))]
+    for tr in trans:
+        bins.append(draw(tr[bins[-1]]))
+    return np.stack(bins, axis=1)
 
 
 @pytest.fixture(scope="module")
@@ -27,7 +40,9 @@ def instance():
                         trans=jnp.asarray(trans, jnp.float32))
     cj = jnp.asarray(costs, jnp.float32)
     tables = solve_line(chain, cj, sup)
-    bins = sample_chain(chain, jax.random.PRNGKey(0), t)
+    bins = jnp.asarray(_sample_chain_np(np.random.default_rng(0),
+                                        np.asarray(p0, np.float64),
+                                        np.asarray(trans, np.float64), t))
     losses = g[bins]
     casc = strategy.Cascade(support=sup, chain=chain, costs=cj, lam=1.0,
                             line_tables=tables)
@@ -35,19 +50,21 @@ def instance():
 
 
 # Golden digests of the PRE-REFACTOR core.policies implementations on the
-# `instance` fixture traces (generated from the originals at the seed
-# commit, CPU f32): (weighted served_node checksum, weighted n_probed
-# checksum, mean explore_cost, mean served_loss).  The wrappers are gone
-# (PR 2); these digests are the surviving pin of the legacy behaviour.
+# `instance` fixture traces (CPU f32): (weighted served_node checksum,
+# weighted n_probed checksum, mean explore_cost, mean served_loss).  The
+# wrappers are gone; these digests are the surviving pin of the
+# legacy behaviour.  Re-pinned when the traces moved to numpy draws: the
+# registry still reproduced every original digest on the original
+# JAX-drawn traces (threefry_partitionable off), so only the inputs moved.
 GOLDEN = {
-    "recall_index": (193855, 573136, 0.130817, 0.290877),
-    "norecall_threshold": (235742, 556142, 0.144184, 0.286886),
-    "recall_threshold": (217153, 556142, 0.144184, 0.283832),
-    "norecall_patience": (578400, 898800, 0.598088, 0.521401),
-    "oracle": (276277, 596677, 0.140934, 0.257808),
-    "oracle_norecall": (276277, 596677, 0.140934, 0.257808),
-    "always_last": (922397, 242794, 0.75477, 0.526222),
-    "always_first": (0, 320400, 0.095957, 0.471482),
+    "recall_index": (189448, 579454, 0.135013, 0.295978),
+    "norecall_threshold": (243607, 564007, 0.149759, 0.292267),
+    "recall_threshold": (224843, 564007, 0.149759, 0.289257),
+    "norecall_patience": (606621, 927021, 0.608784, 0.52629),
+    "oracle": (272793, 593193, 0.139285, 0.262399),
+    "oracle_norecall": (272793, 593193, 0.139285, 0.262399),
+    "always_last": (922397, 242794, 0.75477, 0.523882),
+    "always_first": (0, 320400, 0.095957, 0.477236),
 }
 
 

@@ -128,9 +128,6 @@ DRYRUN_SMOKE = textwrap.dedent("""
 """)
 
 
-@pytest.mark.xfail(
-    reason="pre-existing seed failure (ROADMAP.md open items)",
-    strict=False)
 @pytest.mark.parametrize("arch", ["qwen3-4b", "phi3.5-moe-42b-a6.6b",
                                   "mamba2-130m"])
 def test_train_step_lowers_on_8_fake_devices(arch):

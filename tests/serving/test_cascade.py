@@ -337,19 +337,40 @@ PROMPT_LEN = 10
 VOCAB = 256
 
 
+def _numpy_params(defs, seed):
+    """`models.param.materialize`'s init recipes with the normal draws
+    taken from numpy's RNG: the de-escalation and wedge scenarios below
+    hinge on random-init losses, which must not move with JAX's PRNG
+    implementation."""
+    import math
+
+    from repro.models.param import ParamDef
+    rng = np.random.default_rng(seed)
+
+    def leaf(d):
+        if d.init in ("zeros", "ones"):
+            return jnp.full(d.shape, float(d.init == "ones"), jnp.float32)
+        std = d.scale
+        if d.init == "fan_in":
+            std /= math.sqrt(max(d.shape[d.fan_axis] if d.shape else 1, 1))
+        return jnp.asarray(std * rng.standard_normal(d.shape), jnp.float32)
+
+    return jax.tree.map(leaf, defs,
+                        is_leaf=lambda x: isinstance(x, ParamDef))
+
+
 @pytest.fixture(scope="module")
 def engine_bank():
     from repro.configs.common import dense_decoder
     from repro.models import model as M
-    from repro.models.param import materialize
     cfg_s = dense_decoder("casc-s", n_layers=2, d_model=64, n_heads=2,
                           n_kv_heads=2, head_dim=32, d_ff=128,
                           vocab=VOCAB, n_segments=2, act="gelu")
     cfg_l = dense_decoder("casc-l", n_layers=3, d_model=96, n_heads=2,
                           n_kv_heads=2, head_dim=48, d_ff=192,
                           vocab=VOCAB, n_segments=3, act="gelu")
-    p_s = materialize(M.model_defs(cfg_s), jax.random.PRNGKey(0))
-    p_l = materialize(M.model_defs(cfg_l), jax.random.PRNGKey(1))
+    p_s = _numpy_params(M.model_defs(cfg_s), 0)
+    p_l = _numpy_params(M.model_defs(cfg_l), 1)
     bank = ModelBank([
         ModelSpec("casc-s", 2, n_lanes=2, cfg=cfg_s, params=p_s),
         ModelSpec("casc-l", 3, n_lanes=1, cfg=cfg_l, params=p_l),

@@ -26,7 +26,8 @@ from repro.serving import runtime as rt
 from repro.serving.obs import (FlightRecorder, MetricsRegistry,
                                Observability, SpanTracer,
                                decision_attribution)
-from repro.serving.obs.export import to_perfetto, write_trace
+from repro.serving.obs.export import (profiler_capture, to_perfetto,
+                                      write_trace)
 from repro.serving.runtime.request import Request
 from repro.serving.runtime.workload import WorkloadSpec, make_workload
 
@@ -390,3 +391,22 @@ def test_serve_report_renders_from_registry():
     assert "2 blocked admissions" in pool_line
     # the console report and the snapshot read the same registry
     assert rep.registry.value("kv_pool_reserve_failures") == 2.0
+
+
+@pytest.mark.parametrize("fails", ["start_trace", "stop_trace"])
+def test_profiler_capture_failure_propagates(monkeypatch, tmp_path, fails):
+    """A run asked for a profiler trace must not finish quietly without
+    one: an error from either end of the capture reaches the caller."""
+    import jax
+
+    def boom(*a, **k):
+        raise RuntimeError(f"{fails} failed")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **k: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda *a, **k: None)
+    monkeypatch.setattr(jax.profiler, fails, boom)
+    with pytest.raises(RuntimeError, match=fails):
+        with profiler_capture(str(tmp_path)):
+            pass
+    with profiler_capture(None):    # no logdir: the profiler is untouched
+        pass
