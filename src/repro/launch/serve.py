@@ -139,7 +139,7 @@ def _build_obs(args, *, policy=None, boundaries=None, casc=None,
             os.path.join(args.obs_dir, "metrics.json")
         args.flight_recorder = args.flight_recorder or args.obs_dir
     if not (args.trace_out or args.metrics_out or args.flight_recorder
-            or args.profile_dir or args.regret):
+            or args.regret):
         return None
     flight = None
     if args.flight_recorder:
@@ -153,8 +153,7 @@ def _build_obs(args, *, policy=None, boundaries=None, casc=None,
     if args.regret:
         from repro.serving.obs.regret import RegretMeter
         regret = RegretMeter(casc)
-    return Observability(flight=flight, ledger=ledger, regret=regret,
-                         profile_dir=args.profile_dir)
+    return Observability(flight=flight, ledger=ledger, regret=regret)
 
 
 def _finish_obs(args, obs: Observability | None,

@@ -245,11 +245,14 @@ def prefill(params, cfg: ModelConfig, batch: dict, cache_len: int, *,
 # --------------------------------------------------------------------------
 
 def decode_segment(params, cfg: ModelConfig, si: int, x: jax.Array,
-                   cache_seg, pos: jax.Array, paged=None, write_mask=None):
+                   cache_seg, pos: jax.Array, paged=None, write_mask=None,
+                   readout_scope: str | None = None):
     """Run segment `si` for one token.  x (B,1,D) -> (x', new_cache,
     readout) where readout is None for ramp-less segments and otherwise
     the full `ramp_readout` pair (logits (B,V), loss proxy (B,)) — the
     serving engine consumes both, so the head matmul runs exactly once.
+    ``readout_scope`` names the readout's `jax.named_scope` (the serving
+    engine's ``readout<node>``).
 
     ``paged`` (attention.PagedKV) + ``write_mask`` route the attention
     layers at the paged KV pool; the per-lane page table and write
@@ -279,7 +282,9 @@ def decode_segment(params, cfg: ModelConfig, si: int, x: jax.Array,
         x, new_cache = jax.lax.scan(body, x, (p_seg, cache_seg))
     readout = None
     if seg.ramp:
-        readout = ramp_readout(params, cfg, x[:, 0, :], segment=si)
+        with (jax.named_scope(readout_scope) if readout_scope
+              else contextlib.nullcontext()):
+            readout = ramp_readout(params, cfg, x[:, 0, :], segment=si)
     return x, new_cache, readout
 
 

@@ -47,6 +47,7 @@ from repro.serving.cascade.bank import ModelBank
 from repro.serving.cascade.metrics import CascadeStats
 from repro.serving.cascade.router import CascadeRouter
 from repro.serving.cascade.scheduler import EscalationScheduler
+from repro.serving.obs.trace import TRACER
 from repro.serving.runtime.request import Request
 from repro.serving.runtime.scheduler import EngineStepper
 
@@ -94,6 +95,19 @@ class CascadeEngineStepper:
         self._tracer = t
         for st in self.steppers:
             st.tracer = t
+
+    # the serve's span tracer, fanned out the same way
+    _spans = TRACER
+
+    @property
+    def spans(self):
+        return self._spans
+
+    @spans.setter
+    def spans(self, t) -> None:
+        self._spans = t
+        for st in self.steppers:
+            st.spans = t
 
     def __init__(self, bank: ModelBank, strategies: tuple, *,
                  cache_len: int, prompt_len: int, page_size: int = 16,

@@ -298,12 +298,14 @@ def make_token_step(params, cfg: ModelConfig, strategies, *,
                 seg_batch = seg_batch + active.any().astype(jnp.int32)
                 seg_policy = seg_policy + active.sum(dtype=jnp.int32)
 
+                @jax.named_scope(f"segment{si}")
                 def run(ops, si=si, node=node):
                     x, cache, states, act, best = ops
                     x2, nc, ro = M.decode_segment(
                         params, cfg, si, x, cache, pos,
                         paged=kv if paged else None,
-                        write_mask=act if paged else None)
+                        write_mask=act if paged else None,
+                        readout_scope=f"readout{node}")
                     nc = _mask_lane_writes(nc, cache, act, paged=paged)
                     if ro is not None:
                         # ramp readout: serve-from-this-node logits for
@@ -311,8 +313,10 @@ def make_token_step(params, cfg: ModelConfig, strategies, *,
                         # head matmul via models.model.ramp_readout;
                         # recall refreshes happen via serve()'s argmin
                         # bookkeeping)
-                        states, act, best = fold_readout(
-                            strategies, states, node, *ro, act, sid, best)
+                        with jax.named_scope(f"readout{node}"):
+                            states, act, best = fold_readout(
+                                strategies, states, node, *ro, act, sid,
+                                best)
                     return (x2, nc, states, act, best)
 
                 ops = (x, caches[si], states, active, best_logits)
@@ -321,6 +325,7 @@ def make_token_step(params, cfg: ModelConfig, strategies, *,
                 if seg.ramp:
                     node += 1
 
+        @jax.named_scope(f"readout{node}")
         def run_head(ops):
             x, states, act, best = ops
             logits, ell = M.ramp_readout(params, cfg, x[:, 0, :])
@@ -342,6 +347,7 @@ def make_token_step(params, cfg: ModelConfig, strategies, *,
             # (occupied excludes them), so the only shared state is the
             # page pool, where writes land in disjoint pages.
             with kernel_ctx():
+                @jax.named_scope("chunk_sweep")
                 def run_chunk(cs):
                     xc = params["embed"]["table"][chunk.tok]
                     cs = list(cs)

@@ -5,14 +5,32 @@ One package threads through every serving subsystem:
   * `trace`    — `SpanTracer`: bounded host-side ring of lifecycle
     events (queued → admitted → prefill chunks → per-token decode →
     escalate/recall/de-escalate → finish), fed only from data the
-    steppers already sync once per token.  Zero overhead when absent:
-    every producer guards with ``if tracer is not None``.
+    steppers already sync once per token, and opt-in: every producer
+    guards with ``if tracer is not None``.  Its second ring holds
+    interval spans at the serving path's layer boundaries, of step and
+    request granularity and always on (nothing turns them off):
+    ``server.iteration``, ``request.queue``, ``admission.blocked``,
+    ``engine.admit``, ``engine.step`` (children ``engine.plan``,
+    ``engine.page_ops``, ``engine.dispatch``, ``engine.sync``, with the
+    step's upload and segment counts and its public `StepRecord`) and
+    ``pool.prepare_step`` / ``admit`` / ``release`` /
+    ``commit_prefix``.  `Server.serve` starts a session on the process
+    tracer `TRACER` (on ``obs.tracer`` when it has an `Observability`),
+    which clears the span ring: it holds the last serve until the next
+    starts.  Each live span is mirrored into an active
+    ``jax.profiler`` trace as a ``TraceAnnotation`` carrying its
+    ``span_id``.  No annotation covers a whole serve or window (a
+    trace reader names each idle gap by the innermost span covering
+    it, so one would name them all), and no device scope
+    (``jax.named_scope``) names a kernel (the trace reduction would
+    count the scope's operations as that kernel): the pool relayout is
+    ``kv_layout``, not ``paged_attention``.
   * `registry` — `MetricsRegistry`: counters/gauges/histograms with
     labels, absorbing the per-subsystem stats dicts behind one
     ``snapshot()`` / Prometheus-text / JSON surface.
   * `export`   — Chrome/Perfetto trace-event JSON (one track per
-    lane, one per model rung, decision instants) + optional
-    ``jax.profiler`` capture hooks.
+    lane, one per model rung, decision instants) + an optional
+    ``jax.profiler`` capture around a serve (`profiler_capture`).
   * `flight`   — `FlightRecorder`: last-N-events post-mortem bundles
     on anomaly triggers (TTFT-SLO breach burst, page exhaustion,
     stuck escalation waiter, gear thrash).
@@ -44,7 +62,8 @@ from repro.serving.obs.flight import FlightRecorder
 from repro.serving.obs.pareto import ParetoTracker
 from repro.serving.obs.regret import RegretMeter, regret_events
 from repro.serving.obs.registry import MetricsRegistry
-from repro.serving.obs.trace import SpanTracer, decision_attribution
+from repro.serving.obs.trace import (TRACER, Span, SpanTracer, StepRecord,
+                                     decision_attribution)
 
 __all__ = [
     "FlightRecorder",
@@ -53,7 +72,10 @@ __all__ = [
     "Observability",
     "ParetoTracker",
     "RegretMeter",
+    "Span",
     "SpanTracer",
+    "StepRecord",
+    "TRACER",
     "audit_events",
     "decision_attribution",
     "regret_events",
@@ -63,13 +85,11 @@ __all__ = [
 @dataclass
 class Observability:
     """What a `Server` threads through a serve: a tracer (always, when
-    observability is on), an optional flight recorder, invariant
-    ledger and regret meter riding the same event stream, and an
-    optional ``jax.profiler`` logdir for kernel-level capture around
-    token steps."""
+    observability is on; it then takes the serve's spans too), and an
+    optional flight recorder, invariant ledger and regret meter riding
+    the same event stream."""
 
     tracer: SpanTracer = field(default_factory=SpanTracer)
     flight: FlightRecorder | None = None
     ledger: InvariantLedger | None = None
     regret: RegretMeter | None = None
-    profile_dir: str | None = None

@@ -175,9 +175,10 @@ def write_events(tracer, path: str, *, faults=None) -> dict[str, Any]:
 
 @contextlib.contextmanager
 def profiler_capture(logdir: str | None):
-    """Optional ``jax.profiler`` capture around the serve loop for
-    kernel-level attribution against `bench_roofline.py`.  A no-op
-    when ``logdir`` is falsy.  When it is given, an error starting or
+    """Optional ``jax.profiler`` capture around the serve loop, for
+    an operator's kernel-level look (``--profile-dir``): the serve's
+    spans appear in it as host annotations.  A no-op when ``logdir``
+    is falsy.  When it is given, an error starting or
     stopping the trace propagates: a run that was asked for a trace
     does not succeed without one."""
     if not logdir:

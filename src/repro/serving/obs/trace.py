@@ -1,12 +1,12 @@
-"""`SpanTracer` — bounded host-side ring buffer of request lifecycle
-events (DESIGN.md §12).
+"""`SpanTracer` — bounded host-side rings of request lifecycle events
+and of interval spans (DESIGN.md §12).
 
 Every event is one `Event` record ``(t, kind, rid, lane, model,
 data)`` appended by whichever subsystem observed it; the producers
 only ever touch data they already sync to the host once per token
 (the served/emitted arrays, the router's slot maps, the pool's page
-counters), so the jitted device program is untouched and a serve with
-no tracer attached pays nothing beyond ``if tracer is not None``.
+counters), so the jitted device program is untouched.  Lifecycle
+events are opt-in: a serve with no `Observability` emits none.
 
 Event kinds (the schema CI validates in `benchmarks/check_trace.py`):
 
@@ -31,11 +31,27 @@ Event kinds (the schema CI validates in `benchmarks/check_trace.py`):
   deadline_miss deadline expired, request reaped (rid, lane?)
   rung_stall    fault window froze a model rung  (model, t0, until)
 
-Two digests:
+Spans are intervals at the serving path's layer boundaries, of step
+and request granularity, and always on: `Server.serve` starts a
+session on the process tracer `TRACER` (or on its `Observability`'s
+tracer), which clears the span ring, so the ring holds the last serve
+until the next one starts, however that serve ended.  ``with
+tracer.span(name, rid=, lane=, **counts) as s`` records name, start,
+end, the enclosing open span as parent, and counts (``s.add``) taken at
+that boundary; it also enters ``jax.profiler.TraceAnnotation(name,
+span_id=s.id)``, so an active profiler holds the same interval, on its
+own clock, joinable by ``span_id``.  `record` keeps a span whose start
+lies in the past (a request's wait from its arrival) in memory only.
+A process-wide `jax.monitoring` listener adds every backend compile to
+the innermost open span (``compiles``).  The vocabulary and the
+metrics that read each span are in DESIGN.md §12.
 
-  * `span_digest()` hashes the FULL ring — kinds, ids and virtual
-    timestamps — so a seeded sim serve pins byte-for-byte (the golden
-    value lives in tests, same idiom as the strategy goldens).
+Two digests, over the lifecycle events only (spans carry host-clock
+durations and stay out of both):
+
+  * `span_digest()` hashes the FULL event ring — kinds, ids and
+    virtual timestamps — so a seeded sim serve pins byte-for-byte (the
+    golden value lives in tests, same idiom as the strategy goldens).
   * `decision_digest()` hashes only the per-request decision streams
     (rid → ordered served nodes), which is invariant to arrival
     order and lane placement — the tracer-level mirror of the
@@ -46,10 +62,25 @@ from __future__ import annotations
 
 import collections
 import hashlib
+import itertools
+import threading
+import time
 from dataclasses import dataclass
-from typing import Any, Callable, Iterable
+from typing import Any, Callable, Iterable, NamedTuple
 
-__all__ = ["Event", "SpanTracer", "decision_attribution"]
+import jax
+import numpy as np
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Event", "Span", "SpanTracer", "StepRecord", "TRACER",
+           "decision_attribution"]
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# span ids are unique in the process, so the spans of several tracers
+# never collide in one profiler trace
+_IDS = itertools.count(1)
+# the open spans of this thread, innermost last, as (tracer, span)
+_OPEN = threading.local()
 
 
 @dataclass(frozen=True, slots=True)
@@ -73,10 +104,112 @@ class Event:
         return d
 
 
-class SpanTracer:
-    """Bounded ring of `Event`s + per-request live span index.
+class Span:
+    """One interval on the serve's clock.  ``parent`` is the id of the
+    span that was open around it (-1: none); ``data`` holds the counts
+    taken at its boundaries.  Opened by ``with tracer.span(...)``, which
+    also mirrors it into an active profiler trace."""
 
-    ``capacity`` bounds the ring; ``span_events`` bounds any single
+    __slots__ = ("id", "name", "t0", "t1", "parent", "rid", "lane", "data",
+                 "_tr", "_ann")
+
+    def __init__(self, id: int, name: str, t0: float,
+                 t1: float = float("nan"), parent: int = -1, rid: int = -1,
+                 lane: int = -1, data: dict | None = None, tr=None):
+        self.id, self.name, self.t0, self.t1 = id, name, t0, t1
+        self.parent, self.rid, self.lane, self.data = parent, rid, lane, data
+        self._tr = tr
+
+    def __repr__(self) -> str:
+        return (f"Span({self.id}, {self.name!r}, {self.t0!r}, {self.t1!r}, "
+                f"parent={self.parent}, rid={self.rid}, lane={self.lane}, "
+                f"data={self.data!r})")
+
+    @property
+    def duration(self) -> float:
+        return self.t1 - self.t0
+
+    def add(self, **counts: Any) -> None:
+        if self.data is None:
+            self.data = counts
+        else:
+            self.data.update(counts)
+
+    def count(self, key: str, n: int = 1) -> None:
+        if self.data is None:
+            self.data = {}
+        self.data[key] = self.data.get(key, 0) + n
+
+    def __enter__(self) -> "Span":
+        tr = self._tr
+        stack = getattr(_OPEN, "stack", None)
+        if stack is None:
+            stack = _OPEN.stack = []
+        for owner, sp in reversed(stack):
+            if owner is tr:
+                self.parent = sp.id
+                break
+        stack.append((tr, self))
+        self._ann = TraceAnnotation(self.name, span_id=self.id)
+        self.t0 = tr.now()
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._ann.__exit__(*exc)
+        tr = self._tr
+        self.t1 = tr.now()
+        self._ann = None
+        stack = _OPEN.stack
+        for i in range(len(stack) - 1, -1, -1):
+            if stack[i][1] is self:
+                del stack[i]
+                break
+        tr._keep(self)
+
+
+class StepRecord(NamedTuple):
+    """What one fused step did, as small int64 arrays: the public
+    per-step record, kept as ``data["record"]`` of its ``engine.step``
+    span (the step's start and end are the span's)."""
+
+    decode: np.ndarray      # (k, 5): lane, rid, position, node, token
+    chunks: np.ndarray      # (m, 5): lane, rid, start, width, done
+    firsts: np.ndarray      # (f, 3): lane, rid, first token
+
+    @classmethod
+    def of(cls, decode: tuple, chunks: list, firsts: tuple) -> "StepRecord":
+        """From the decode and first-token columns (equal-length arrays)
+        and the chunks' rows."""
+        return cls(_columns(decode), np.asarray(chunks, np.int64).reshape(
+            -1, 5), _columns(firsts))
+
+
+def _columns(cols: tuple) -> np.ndarray:
+    out = np.empty((len(cols[0]), len(cols)), np.int64)
+    for i, c in enumerate(cols):
+        out[:, i] = c
+    return out
+
+
+def _on_compile(event: str, secs: float, **_: Any) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    stack = getattr(_OPEN, "stack", None)
+    if stack:
+        stack[-1][1].count("compiles")
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile)
+
+
+class SpanTracer:
+    """Bounded ring of `Event`s + per-request live span index, and a
+    bounded ring of interval `Span`s (``spans``, 128k: a serve of a few
+    thousand steps; ``spans_dropped`` counts its evictions this
+    session).
+
+    ``capacity`` bounds the event ring; ``span_events`` bounds any single
     request's indexed span (events past the cap are counted, not
     kept); ``keep_finished`` bounds how many completed spans stay
     addressable for post-mortems and tests.  Everything is O(1)
@@ -98,13 +231,56 @@ class SpanTracer:
         self._clock: Callable[[], float] | None = None
         self.listener: Callable[[Event], None] | None = None
         self.n_emitted = 0
+        # interval spans: their own ring, out of the event digests
+        self.spans: collections.deque[Span] = collections.deque(
+            maxlen=1 << 17)
+        self.spans_dropped = 0    # span ring evictions this session
 
     # ---------------------------------------------------------- wiring
-    def bind_clock(self, clock: Callable[[], float]) -> None:
+    def bind_clock(self, clock: Callable[[], float] | None) -> None:
         """Events emitted without an explicit ``t`` stamp from here —
         the server binds its own clock (virtual in sim mode, so the
         whole trace is deterministic)."""
         self._clock = clock
+
+    def begin_session(self, clock: Callable[[], float]) -> None:
+        """Start a serve: bind its clock and clear the span ring (the
+        lifecycle events are left as they are)."""
+        self.bind_clock(clock)
+        self.spans.clear()
+        self.spans_dropped = 0
+
+    def now(self) -> float:
+        """The bound clock, else `time.perf_counter`."""
+        return self._clock() if self._clock is not None \
+            else time.perf_counter()
+
+    # ---------------------------------------------------------- spans
+    def span(self, name: str, rid: int = -1, lane: int = -1,
+             **counts: Any) -> Span:
+        """``with tracer.span(name) as s:`` records the interval the
+        block takes, and mirrors it into an active profiler trace."""
+        return Span(next(_IDS), name, 0.0, rid=int(rid), lane=int(lane),
+                    data=counts or None, tr=self)
+
+    def record(self, name: str, t0: float, t1: float | None = None, *,
+               rid: int = -1, lane: int = -1, **counts: Any) -> Span:
+        """Keep a span that started in the past (in memory only: the
+        profiler cannot be told of it after the fact)."""
+        span = Span(next(_IDS), name, float(t0),
+                    float(self.now() if t1 is None else t1),
+                    rid=int(rid), lane=int(lane), data=counts or None)
+        self._keep(span)
+        return span
+
+    def _keep(self, span: Span) -> None:
+        if len(self.spans) == self.spans.maxlen:
+            self.spans_dropped += 1
+        self.spans.append(span)
+
+    def named(self, name: str) -> list[Span]:
+        """The ring's spans called ``name``, oldest first."""
+        return [s for s in self.spans if s.name == name]
 
     def add_listener(self, fn: Callable[[Event], None]) -> None:
         """Chain ``fn`` onto the listener hook so several consumers
@@ -205,6 +381,11 @@ class SpanTracer:
             "live_spans": len(self._live),
             "finished_spans": len(self._done),
         }
+
+
+# the process's tracer (cf. `prometheus_client.REGISTRY`): every serve
+# without an `Observability` records its spans here
+TRACER = SpanTracer()
 
 
 def decision_attribution(events: Iterable[Event],

@@ -56,6 +56,7 @@ import numpy as np
 from repro.models import model as M
 from repro.models.attention import PagedKV, PrefillChunk
 from repro.serving.engine import make_token_step
+from repro.serving.obs.trace import TRACER, StepRecord
 from repro.serving.runtime.request import Request, RequestQueue
 from repro.strategy.base import init_lane
 
@@ -224,6 +225,9 @@ class EngineStepper:
     # observability plane (DESIGN.md §12): installed by the server when
     # tracing is on; every producer guards on `is not None`
     tracer = None
+    # step- and request-granular spans, always on: the server installs
+    # the tracer of its serve
+    spans = TRACER
 
     def __init__(self, params, cfg, strategies: tuple, *, n_lanes: int,
                  cache_len: int, prompt_len: int, jit: bool = True,
@@ -264,6 +268,7 @@ class EngineStepper:
         self.planner = None if prefill_chunk is None else ChunkPlanner(
             self.prefill_chunk, prefill_budget)
         self.walk_io = bool(walk_io)
+        self._n_up = self._up_bytes = 0     # host->device arrays made
         self._step = make_token_step(params, cfg, strategies, jit=jit,
                                      donate=False, carry_state=True,
                                      paged=(kv == "paged"),
@@ -349,6 +354,7 @@ class EngineStepper:
         return admit_fn
 
     @staticmethod
+    @jax.named_scope("page_reset")
     def _reset_pages(caches, pages):
         """Gate the stale bytes of freshly allocated pages before a
         chunked admission starts writing into them: pos[:, pages] = -1
@@ -366,6 +372,7 @@ class EngineStepper:
         return out
 
     @staticmethod
+    @jax.named_scope("page_prep")
     def _paged_prep(caches, fresh, cow_src, cow_dst):
         """Pre-step page ops: COW page copies (src -> dst across every
         attention layer — page ids are global) and fresh-page position
@@ -396,6 +403,10 @@ class EngineStepper:
         self.caches = [_materialize_cache(s) for s in specs]
         self.tok = jnp.zeros((self.n_lanes,), jnp.int32)
         self.pos = jnp.zeros((self.n_lanes,), jnp.int32)
+        # host views for the per-step record: each lane's request and
+        # the position its next decode token is written at
+        self.lane_rids = np.full(self.n_lanes, -1, np.int64)
+        self.host_pos = np.zeros(self.n_lanes, np.int64)
         self.states = tuple(s.init(self.n_lanes) for s in self.strategies)
         # chunked-prefill lane state: lane -> {prompt, plan, cursor, lp}
         self._prefilling = {}
@@ -417,9 +428,12 @@ class EngineStepper:
         A lane reaped mid-chunked-prefill (fault plane) also drops its
         prefill cursor — otherwise the freed lane would keep receiving
         chunk plans."""
-        self._prefilling.pop(lane, None)
-        if self.pool is not None:
-            self.pool.release(lane)
+        with self.spans.span("pool.release", rid=self.lane_rids[lane],
+                             lane=lane):
+            self._prefilling.pop(lane, None)
+            self.lane_rids[lane] = -1
+            if self.pool is not None:
+                self.pool.release(lane)
 
     def admit(self, lane: int, req: Request) -> None:
         """Admit the request into ``lane``.
@@ -434,11 +448,22 @@ class EngineStepper:
         lifts the fixed prompt bucket: any prompt that fits the lane's
         page capacity is admissible (chunks are the static shape, not
         the prompt)."""
+        with self.spans.span("engine.admit", rid=req.rid,
+                             lane=lane) as span:
+            n_up = self._n_up
+            self.lane_rids[lane] = req.rid
+            self._admit_lane(lane, req)
+            span.add(uploads=self._n_up - n_up)
+
+    def _admit_lane(self, lane: int, req: Request) -> None:
+        spans = self.spans
         if self.prefill_chunk is not None:
-            plan = self.pool.admit(lane, req.prompt, req.max_tokens,
-                                   register_prefix=False)
-            self.caches = self._reset(self.caches,
-                                      jnp.asarray(plan.new_pages))
+            with spans.span("pool.admit", rid=req.rid, lane=lane):
+                plan = self.pool.admit(lane, req.prompt, req.max_tokens,
+                                       register_prefix=False)
+            with spans.span("engine.page_ops", op="reset"):
+                self.caches = self._reset(self.caches,
+                                          self._put(plan.new_pages))
             lp = int(req.prompt.shape[0])
             # full prefix hit still recomputes the final token: the
             # first-token logits need the last position's hidden state
@@ -457,21 +482,31 @@ class EngineStepper:
             raise ValueError(
                 f"request {req.rid}: prompt length {req.prompt.shape[0]} "
                 f"!= stepper bucket {self.prompt_len} (static shapes)")
-        prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
+        prompt = self._put(req.prompt, jnp.int32)[None, :]
         if self.pool is not None:
-            plan = self.pool.admit(lane, req.prompt, req.max_tokens)
+            with spans.span("pool.admit", rid=req.rid, lane=lane):
+                plan = self.pool.admit(lane, req.prompt, req.max_tokens)
             self.caches, self.tok, self.pos = self._admit(
-                self.caches, self.tok, self.pos, prompt, jnp.int32(lane),
-                jnp.asarray(plan.dest_page), jnp.asarray(plan.dest_slot),
-                jnp.asarray(plan.pos_vals), jnp.asarray(plan.new_pages))
+                self.caches, self.tok, self.pos, prompt,
+                self._put(lane, jnp.int32), self._put(plan.dest_page),
+                self._put(plan.dest_slot), self._put(plan.pos_vals),
+                self._put(plan.new_pages))
         else:
             self.caches, self.tok, self.pos = self._admit(
                 self.caches, self.tok, self.pos, prompt,
-                jnp.int32(lane))
+                self._put(lane, jnp.int32))
+        self.host_pos[lane] = self.prompt_len
         # pytree-sliced per-lane reset: the recycled lane starts from
         # fresh strategy state no matter what its predecessor observed
         self.states = tuple(init_lane(s, st, lane)
                             for s, st in zip(self.strategies, self.states))
+
+    def _put(self, x, dtype=None) -> jax.Array:
+        """One host->device array, counted for the step's span."""
+        out = jnp.asarray(x, dtype)
+        self._n_up += 1
+        self._up_bytes += out.nbytes
+        return out
 
     def set_lane_token(self, lane: int, token: int) -> None:
         """Override a lane's next input token — the cascade router uses
@@ -516,8 +551,10 @@ class EngineStepper:
         `PrefillChunk` (all-idle when nothing is prefilling: position
         -1 rows, garbage destinations — the step's lax.cond skips the
         sweep).  Advances the per-lane cursors and returns the lanes
-        whose prompt finishes with this chunk."""
+        whose prompt finishes with this chunk, and the chunks' rows of
+        the step record (lane, rid, start, width, done)."""
         n, c = self.n_lanes, self.prefill_chunk
+        rows = []
         if not widths:
             if self._idle_chunk is None:
                 zi = jnp.zeros((n, c), jnp.int32)
@@ -527,7 +564,7 @@ class EngineStepper:
                     tok=zi, pos=jnp.full((n, c), -1, jnp.int32),
                     dest_page=zi, dest_slot=zi, start=z1, last_idx=z1,
                     emit=zb, active=zb)
-            return self._idle_chunk, []
+            return self._idle_chunk, [], rows
         tok = np.zeros((n, c), np.int32)
         pos = np.full((n, c), -1, np.int32)
         dp = np.zeros((n, c), np.int32)     # 0 == the garbage sink
@@ -549,9 +586,11 @@ class EngineStepper:
             last[lane] = w - 1
             act[lane] = True
             st["cursor"] = cur + w
-            if st["cursor"] == st["lp"]:
+            done = st["cursor"] == st["lp"]
+            if done:
                 emit[lane] = True
                 finished.append(lane)
+            rows.append((lane, st.get("rid", -1), cur, w, done))
             self.chunk_stats["tokens_computed"] += w
             if self.tracer is not None:
                 self.tracer.emit(
@@ -559,12 +598,12 @@ class EngineStepper:
                     rid=int(st.get("rid", -1)), width=int(w),
                     left=int(st["lp"] - st["cursor"]))
         self.chunk_stats["chunk_steps"] += 1
+        put = self._put
         chunk = PrefillChunk(
-            tok=jnp.asarray(tok), pos=jnp.asarray(pos),
-            dest_page=jnp.asarray(dp), dest_slot=jnp.asarray(ds),
-            start=jnp.asarray(start), last_idx=jnp.asarray(last),
-            emit=jnp.asarray(emit), active=jnp.asarray(act))
-        return chunk, finished
+            tok=put(tok), pos=put(pos), dest_page=put(dp),
+            dest_slot=put(ds), start=put(start), last_idx=put(last),
+            emit=put(emit), active=put(act))
+        return chunk, finished, rows
 
     def step(self, occupied: np.ndarray, sid: np.ndarray, walk=None):
         """One fused step: a decode token for every occupied DECODING
@@ -583,72 +622,110 @@ class EngineStepper:
         ``(walk_active (B,) bool host, best_logits device)``: the
         escalation handoff the cascade router stashes for the next
         ladder model.
+
+        The step is the ``engine.step`` span, with children
+        ``engine.plan`` (holding ``pool.prepare_step`` and
+        ``engine.page_ops``), ``engine.dispatch``, ``pool.commit_prefix``
+        and ``engine.sync``; its data holds the counts ``uploads``,
+        ``upload_bytes``, ``seg_batch``, ``seg_policy`` (and
+        ``compiles``) and the public per-step record (``record``, a
+        `StepRecord`).
         """
-        occ_np = np.asarray(occupied, bool)
-        decode = occ_np.copy()
-        widths: dict = {}
-        if self.prefill_chunk is not None and self._prefilling:
-            for lane in self._prefilling:
-                decode[lane] = False
-            widths = self.planner.plan({
-                lane: (st["lp"] - st["cursor"], st["lp"])
-                for lane, st in self._prefilling.items()})
-        occ = jnp.asarray(decode, bool)
-        sid_d = jnp.asarray(sid, jnp.int32)
-        if self.walk_io and walk is None:
-            walk = (jnp.ones((self.n_lanes,), bool),
-                    jnp.zeros((self.n_lanes, self.cfg.vocab), jnp.float32))
+        with self.spans.span("engine.step") as span:
+            return self._fused_step(occupied, sid, walk, span)
+
+    def _fused_step(self, occupied, sid, walk, span):
+        spans = self.spans
+        n_up, up_bytes = self._n_up, self._up_bytes
+        decode = np.asarray(occupied, bool).copy()
         finished: list = []
-        if self.pool is not None:
-            plan = self.pool.prepare_step(decode)
-            if plan.fresh.any() or plan.cow_dst.any():
-                # page ops only when the plan has any (steady-state
-                # mid-page decode skips the dispatch + pool rewrite)
-                self.caches = self._prep(self.caches,
-                                         jnp.asarray(plan.fresh),
-                                         jnp.asarray(plan.cow_src),
-                                         jnp.asarray(plan.cow_dst))
-            kv = PagedKV(page_table=jnp.asarray(self.pool.table),
-                         write_page=jnp.asarray(plan.write_page),
-                         write_slot=jnp.asarray(plan.write_slot))
-            args = (self.tok, self.caches, self.pos, occ, sid_d, kv,
-                    self.states)
-            if self.prefill_chunk is not None:
-                chunk, finished = self._build_chunk(widths)
-                args = args + (chunk,)
-            elif self.walk_io:
-                args = args + (None,)
+        rows: list = []
+        with spans.span("engine.plan"):
+            widths: dict = {}
+            if self.prefill_chunk is not None and self._prefilling:
+                for lane in self._prefilling:
+                    decode[lane] = False
+                widths = self.planner.plan({
+                    lane: (st["lp"] - st["cursor"], st["lp"])
+                    for lane, st in self._prefilling.items()})
+            occ = self._put(decode, bool)
+            sid_d = self._put(sid, jnp.int32)
+            if self.walk_io and walk is None:
+                walk = (jnp.ones((self.n_lanes,), bool),
+                        jnp.zeros((self.n_lanes, self.cfg.vocab),
+                                  jnp.float32))
+            if self.pool is not None:
+                with spans.span("pool.prepare_step"):
+                    plan = self.pool.prepare_step(decode)
+                if plan.fresh.any() or plan.cow_dst.any():
+                    # page ops only when the plan has any (steady-state
+                    # mid-page decode skips the dispatch + pool rewrite);
+                    # dispatched before the chunk is built, so the old
+                    # pool is freed before the step allocates its output
+                    with spans.span("engine.page_ops", op="prep"):
+                        self.caches = self._prep(self.caches,
+                                                 self._put(plan.fresh),
+                                                 self._put(plan.cow_src),
+                                                 self._put(plan.cow_dst))
+                kv = PagedKV(page_table=self._put(self.pool.table),
+                             write_page=self._put(plan.write_page),
+                             write_slot=self._put(plan.write_slot))
+                args = (self.tok, self.caches, self.pos, occ, sid_d, kv,
+                        self.states)
+                if self.prefill_chunk is not None:
+                    chunk, finished, rows = self._build_chunk(widths)
+                    args = args + (chunk,)
+                elif self.walk_io:
+                    args = args + (None,)
+            else:
+                args = (self.tok, self.caches, self.pos, occ, sid_d, None,
+                        self.states)
+                if self.walk_io:
+                    args = args + (None,)
             if self.walk_io:
                 args = args + (walk,)
+        with spans.span("engine.dispatch"):
             out = self._step(*args)
+        if self.pool is not None:
             self.pool.note_written(decode)
-        else:
-            args = (self.tok, self.caches, self.pos, occ, sid_d, None,
-                    self.states)
-            if self.walk_io:
-                args = args + (None, walk)
-            out = self._step(*args)
         if self.walk_io:
             tok, self.caches, served, sb, sp, self.states, walk_out = out
         else:
             tok, self.caches, served, sb, sp, self.states = out
         self.tok = tok
         self.pos = self.pos + occ.astype(jnp.int32)
+        lanes = np.flatnonzero(decode)
+        pos_h = self.host_pos[lanes]
+        self.host_pos[lanes] += 1
         if finished:
             # the final chunk seeded tok[lane] with the first token
             # (inside the fused step); point the lane past its prompt
             # and make its pages shareable now that every byte exists
-            lanes = jnp.asarray(finished, jnp.int32)
-            lps = jnp.asarray(
-                [self._prefilling[ln]["lp"] for ln in finished], jnp.int32)
-            self.pos = self.pos.at[lanes].set(lps)
+            lps = [self._prefilling[ln]["lp"] for ln in finished]
+            self.pos = self.pos.at[self._put(finished, jnp.int32)].set(
+                self._put(lps, jnp.int32))
+            self.host_pos[finished] = lps
             for lane in finished:
                 st = self._prefilling.pop(lane)
-                self.pool.commit_prefix(lane, st["prompt"])
+                with spans.span("pool.commit_prefix", rid=st["rid"],
+                                lane=lane):
+                    self.pool.commit_prefix(lane, st["prompt"])
+        with spans.span("engine.sync"):
+            if self.walk_io:
+                tok_h, served_h, sb_h, sp_h, wa_h = jax.device_get(
+                    (tok, served, sb, sp, walk_out[0]))
+            else:
+                tok_h, served_h, sb_h, sp_h = jax.device_get(
+                    (tok, served, sb, sp))
+        fin = np.asarray(finished, np.int64)
+        span.add(uploads=self._n_up - n_up,
+                 upload_bytes=self._up_bytes - up_bytes,
+                 seg_batch=int(sb_h), seg_policy=int(sp_h),
+                 record=StepRecord.of(
+                     (lanes, self.lane_rids[lanes], pos_h, served_h[lanes],
+                      tok_h[lanes]), rows,
+                     (fin, self.lane_rids[fin], tok_h[fin])))
         if self.walk_io:
-            tok_h, served_h, sb_h, sp_h, wa_h = jax.device_get(
-                (tok, served, sb, sp, walk_out[0]))
             return (tok_h, served_h, int(sb_h), int(sp_h), decode,
                     (wa_h, walk_out[1]))
-        tok_h, served_h, sb_h, sp_h = jax.device_get((tok, served, sb, sp))
         return tok_h, served_h, int(sb_h), int(sp_h), decode

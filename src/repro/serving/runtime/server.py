@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.serving.engine import bank_observe, bank_serve
+from repro.serving.obs.trace import TRACER
 from repro.serving.runtime.metrics import RuntimeMetrics
 from repro.serving.runtime.request import Request, RequestQueue
 from repro.serving.runtime.scheduler import LaneScheduler
@@ -488,9 +489,26 @@ class Server:
         ``warmup`` compiles the stepper's device programs before the
         serving clock starts, so wall-clock latency percentiles measure
         serving, not XLA compilation.
+
+        The serve's spans go to ``obs.tracer``, else to the process
+        tracer `TRACER`, whose ring keeps them after the serve however
+        it ends; the process tracer then lets go of this server's clock,
+        so it keeps nothing of the serve alive but its spans.
         """
+        try:
+            return self._serve(requests, warmup)
+        finally:
+            if self.obs is None:
+                TRACER.bind_clock(None)
+
+    def _serve(self, requests, warmup: bool) -> RuntimeMetrics:
         sched = self.scheduler
         stepper = self.stepper
+        # step- and request-granular spans are always on (DESIGN.md
+        # §12): into the Observability's tracer, else the process's
+        spans = self.obs.tracer if self.obs is not None else TRACER
+        if hasattr(stepper, "spans"):
+            stepper.spans = spans
         if warmup:
             stepper.warmup()
         else:
@@ -539,145 +557,164 @@ class Server:
         self._vt = 0.0
         self._t0 = time.perf_counter()
         metrics.t_start = self._now()
+        spans.begin_session(self._now)
 
         # paged-KV steppers gate admission on their free-page budget
         # (reserve-at-pop); a blocked request waits at the queue head
         gate = getattr(stepper, "reserve", None)
         release = getattr(stepper, "release", None)
-        if gate is not None and tracer is not None:
+        refused = [False]       # the gate refused the head this pass
+        if gate is not None:
             def gate(req, _inner=gate):
                 ok = _inner(req)
                 if not ok:
-                    tracer.emit("page_blocked", rid=req.rid)
+                    refused[0] = True
+                    if tracer is not None:
+                        tracer.emit("page_blocked", rid=req.rid)
                 return ok
+        # (start, cause) of the wait since the last admission pass that
+        # left the queue non-empty: no free lane, or no pages for one
+        blocked = None
 
         while pending or len(queue) or sched.busy():
-            now = self._now()
-            if clocked:
-                stepper.fault_now = now
-            if faults is not None:
-                pool = getattr(stepper, "pool", None)
-                if pool is not None and hasattr(pool, "set_squeeze"):
-                    pool.set_squeeze(faults.squeeze_pages(now))
-            pushed = []
-            while pending and pending[0].arrival <= now:
-                req = pending.pop(0)
-                queue.push(req)
-                pushed.append(req.arrival)
-                if tracer is not None:
-                    # self-contained for replay (obs/replay.py): the
-                    # queued event carries everything needed to rebuild
-                    # the request — prompt bytes included, since paged
-                    # admission and prefix sharing key on content
-                    extra = {"plen": len(req.prompt),
-                             "ntok": int(req.max_tokens),
-                             "prompt": np.asarray(
-                                 req.prompt, np.uint32).tobytes().hex()}
-                    if req.strategy is not None:
-                        extra["strategy"] = req.strategy
-                    if req.lam is not None:
-                        extra["lam"] = float(req.lam)
-                    if req.deadline is not None:
-                        extra["deadline"] = float(req.deadline)
-                    if req.cancel_at is not None:
-                        extra["cancel_at"] = float(req.cancel_at)
-                    tracer.emit("queued", t=req.arrival, rid=req.rid,
-                                **extra)
-            if self.controller is not None and pushed:
-                self.controller.on_arrivals(pushed)
-            if reaping:
-                self._reap(queue, metrics, tracer, release, now)
-            for lane, req in sched.admit(
-                    queue, self.sid_of,
-                    static_batching=self.static_batching,
-                    can_admit=gate):
-                stepper.admit(lane, req)
-                metrics.on_admit(req, self._now())
-                if tracer is not None:
-                    tracer.emit("admitted", rid=req.rid, lane=lane,
-                                sid=int(sched.sid[lane]))
-            if not sched.busy():
-                if not pending:
-                    # nothing running, nothing arriving — but the queue
-                    # may still hold page-blocked requests; one more
-                    # admit pass runs next iteration after lanes/pages
-                    # freed (len(queue) keeps the loop alive).  Guard
-                    # against a request that can NEVER be admitted —
-                    # unless the fault plane will change the picture (a
-                    # queued request about to be reaped, a squeeze or
-                    # stall window about to end): then jump there.
-                    if len(queue):
-                        wake = self._fault_wake(queue, faults, reaping,
-                                                now)
-                        if wake is not None and wake > now:
-                            self._advance_to(wake)
-                            continue
-                        raise RuntimeError(
-                            "admission deadlock: queued requests but no "
-                            "lane busy and no pending arrivals")
-                    break
-                # every lane idle and nothing admissible: jump (sim) or
-                # sleep (real) to the next arrival
-                self._advance_to(pending[0].arrival)
-                continue
-
-            occupied = sched.occupied_mask()
-            out = stepper.step(occupied, sched.sid)
-            if stepper.virtual_time:
-                emitted, served, sb, sp, cost, emit = out
-                self._vt += cost
-            else:
-                emitted, served, sb, sp, emit = out
-            tnow = self._now()
-            # emit marks lanes whose entry is a real token this step;
-            # lanes mid-(chunked-)prefill are occupied but still silent
-            metrics.on_step(sb, sp, int(np.asarray(emit).sum()))
-            for lane in np.flatnonzero(emit):
-                req = sched.lane_req[lane]
-                metrics.on_token(req.rid, int(served[lane]), tnow,
-                                 token=int(emitted[lane]))
-                if tracer is not None:
-                    extra = {}
-                    rec = metrics.records[req.rid]
-                    if rec.n_tokens == 1 and rec.ttft is not None:
-                        extra["ttft"] = round(rec.ttft, 9)
-                    ll = getattr(stepper, "last_loss", None)
-                    if ll is not None and not np.isnan(ll[lane]):
-                        extra["loss"] = round(float(ll[lane]), 6)
-                    le = getattr(stepper, "last_escalated", None)
-                    if le is not None and le[lane]:
-                        extra["esc"] = True
-                    ld = getattr(stepper, "last_deepest", None)
-                    if ld is not None and ld[lane] >= 0:
-                        extra["deepest"] = int(ld[lane])
-                    if getattr(stepper, "emits_tokens", True):
-                        extra["tok"] = int(emitted[lane])
-                    tracer.emit("token", rid=req.rid, lane=int(lane),
-                                node=int(served[lane]),
-                                sid=int(sched.sid[lane]), **extra)
-                done = sched.consume_token(lane)
-                if (not done and self.eos is not None
-                        and getattr(stepper, "emits_tokens", True)
-                        and int(emitted[lane]) == self.eos):
-                    done = True  # stream early-exit: recycle immediately
-                if done:
-                    metrics.on_finish(req.rid, tnow)
-                    if release is not None:
-                        release(lane)   # paged KV: pages back to the pool
-                    sched.release(lane)
+            with spans.span("server.iteration"):
+                now = self._now()
+                if clocked:
+                    stepper.fault_now = now
+                if faults is not None:
+                    pool = getattr(stepper, "pool", None)
+                    if pool is not None and hasattr(pool, "set_squeeze"):
+                        pool.set_squeeze(faults.squeeze_pages(now))
+                pushed = []
+                while pending and pending[0].arrival <= now:
+                    req = pending.pop(0)
+                    queue.push(req)
+                    pushed.append(req.arrival)
                     if tracer is not None:
-                        tracer.emit("finish", rid=req.rid, lane=int(lane))
-            if tracer is not None:
-                data = {"queue": len(queue)}
-                pool = getattr(stepper, "pool", None)
-                if pool is not None:
-                    data["pages_in_use"] = int(pool.pages_in_use)
-                tracer.emit("counter", **data)
-            if self.controller is not None:
-                # step boundary: the device program for this step has
-                # fully retired, no lane is mid-token — the one atomic
-                # instant a gear swap / table publish may land
-                self.controller.on_step_end(self._now(), len(queue))
+                        # self-contained for replay (obs/replay.py): the
+                        # queued event carries everything needed to rebuild
+                        # the request — prompt bytes included, since paged
+                        # admission and prefix sharing key on content
+                        extra = {"plen": len(req.prompt),
+                                 "ntok": int(req.max_tokens),
+                                 "prompt": np.asarray(
+                                     req.prompt, np.uint32).tobytes().hex()}
+                        if req.strategy is not None:
+                            extra["strategy"] = req.strategy
+                        if req.lam is not None:
+                            extra["lam"] = float(req.lam)
+                        if req.deadline is not None:
+                            extra["deadline"] = float(req.deadline)
+                        if req.cancel_at is not None:
+                            extra["cancel_at"] = float(req.cancel_at)
+                        tracer.emit("queued", t=req.arrival, rid=req.rid,
+                                    **extra)
+                if self.controller is not None and pushed:
+                    self.controller.on_arrivals(pushed)
+                if reaping:
+                    self._reap(queue, metrics, tracer, release, now)
+                refused[0] = False
+                for lane, req in sched.admit(
+                        queue, self.sid_of,
+                        static_batching=self.static_batching,
+                        can_admit=gate):
+                    stepper.admit(lane, req)
+                    t_adm = self._now()
+                    metrics.on_admit(req, t_adm)
+                    spans.record("request.queue", req.arrival, t_adm,
+                                 rid=req.rid, lane=lane)
+                    if tracer is not None:
+                        tracer.emit("admitted", rid=req.rid, lane=lane,
+                                    sid=int(sched.sid[lane]))
+                t_pass = self._now()
+                if blocked is not None:
+                    spans.record("admission.blocked", blocked[0], t_pass,
+                                 by=blocked[1])
+                blocked = None
+                if len(queue):
+                    blocked = (t_pass, "pages" if refused[0] else "lanes")
+                if not sched.busy():
+                    if not pending:
+                        # nothing running, nothing arriving — but the queue
+                        # may still hold page-blocked requests; one more
+                        # admit pass runs next iteration after lanes/pages
+                        # freed (len(queue) keeps the loop alive).  Guard
+                        # against a request that can NEVER be admitted —
+                        # unless the fault plane will change the picture (a
+                        # queued request about to be reaped, a squeeze or
+                        # stall window about to end): then jump there.
+                        if len(queue):
+                            wake = self._fault_wake(queue, faults, reaping,
+                                                    now)
+                            if wake is not None and wake > now:
+                                self._advance_to(wake)
+                                continue
+                            raise RuntimeError(
+                                "admission deadlock: queued requests but no "
+                                "lane busy and no pending arrivals")
+                        break
+                    # every lane idle and nothing admissible: jump (sim) or
+                    # sleep (real) to the next arrival
+                    self._advance_to(pending[0].arrival)
+                    continue
+
+                occupied = sched.occupied_mask()
+                out = stepper.step(occupied, sched.sid)
+                if stepper.virtual_time:
+                    emitted, served, sb, sp, cost, emit = out
+                    self._vt += cost
+                else:
+                    emitted, served, sb, sp, emit = out
+                tnow = self._now()
+                # emit marks lanes whose entry is a real token this step;
+                # lanes mid-(chunked-)prefill are occupied but still silent
+                metrics.on_step(sb, sp, int(np.asarray(emit).sum()))
+                for lane in np.flatnonzero(emit):
+                    req = sched.lane_req[lane]
+                    metrics.on_token(req.rid, int(served[lane]), tnow,
+                                     token=int(emitted[lane]))
+                    if tracer is not None:
+                        extra = {}
+                        rec = metrics.records[req.rid]
+                        if rec.n_tokens == 1 and rec.ttft is not None:
+                            extra["ttft"] = round(rec.ttft, 9)
+                        ll = getattr(stepper, "last_loss", None)
+                        if ll is not None and not np.isnan(ll[lane]):
+                            extra["loss"] = round(float(ll[lane]), 6)
+                        le = getattr(stepper, "last_escalated", None)
+                        if le is not None and le[lane]:
+                            extra["esc"] = True
+                        ld = getattr(stepper, "last_deepest", None)
+                        if ld is not None and ld[lane] >= 0:
+                            extra["deepest"] = int(ld[lane])
+                        if getattr(stepper, "emits_tokens", True):
+                            extra["tok"] = int(emitted[lane])
+                        tracer.emit("token", rid=req.rid, lane=int(lane),
+                                    node=int(served[lane]),
+                                    sid=int(sched.sid[lane]), **extra)
+                    done = sched.consume_token(lane)
+                    if (not done and self.eos is not None
+                            and getattr(stepper, "emits_tokens", True)
+                            and int(emitted[lane]) == self.eos):
+                        done = True  # stream early-exit: recycle immediately
+                    if done:
+                        metrics.on_finish(req.rid, tnow)
+                        if release is not None:
+                            release(lane)   # paged KV: pages back to the pool
+                        sched.release(lane)
+                        if tracer is not None:
+                            tracer.emit("finish", rid=req.rid, lane=int(lane))
+                if tracer is not None:
+                    data = {"queue": len(queue)}
+                    pool = getattr(stepper, "pool", None)
+                    if pool is not None:
+                        data["pages_in_use"] = int(pool.pages_in_use)
+                    tracer.emit("counter", **data)
+                if self.controller is not None:
+                    # step boundary: the device program for this step has
+                    # fully retired, no lane is mid-token — the one atomic
+                    # instant a gear swap / table publish may land
+                    self.controller.on_step_end(self._now(), len(queue))
 
         metrics.t_end = self._now()
         if self.obs is not None:
