@@ -131,14 +131,15 @@ def _check_serve(metrics, pool, requests, vocab) -> list[str]:
 
 
 def _random_pool(rng, table_lens, maxp, hkv, hd):
-    """bf16 k/v pools with shuffled page ids, and page tables holding
-    positions [0, n) for a lane of length n (0 = idle lane)."""
+    """bf16 k/v pools in the stored layout (DESIGN.md §8) with shuffled
+    page ids, and page tables holding positions [0, n) for a lane of
+    length n (0 = idle lane)."""
     import jax.numpy as jnp
     n_pages = 1 + LANES * maxp
-    shape = (n_pages, PAGE_SIZE, hkv, hd)
+    shape = (n_pages, hkv, hd, PAGE_SIZE)
     k = jnp.asarray(rng.normal(size=shape) * 0.5, jnp.bfloat16)
     v = jnp.asarray(rng.normal(size=shape) * 0.5, jnp.bfloat16)
-    pos = np.full((n_pages, PAGE_SIZE), -1, np.int32)
+    pos = np.full((n_pages, 1, PAGE_SIZE), -1, np.int32)
     table = np.zeros((LANES, maxp), np.int32)
     free = list(rng.permutation(np.arange(1, n_pages)))
     for lane, n in enumerate(table_lens):
@@ -147,9 +148,9 @@ def _random_pool(rng, table_lens, maxp, hkv, hd):
             table[lane, j] = pid
             lo = j * PAGE_SIZE
             w = min(PAGE_SIZE, n - lo)
-            pos[pid, :w] = np.arange(lo, lo + w)
+            pos[pid, 0, :w] = np.arange(lo, lo + w)
             # a mid-page tail holds stale later positions: masked
-            pos[pid, w:] = np.arange(n, n + PAGE_SIZE - w)
+            pos[pid, 0, w:] = np.arange(n, n + PAGE_SIZE - w)
     return k, v, jnp.asarray(pos), jnp.asarray(table)
 
 
@@ -188,8 +189,7 @@ def _check_kernels(cfg, seed: int) -> list[str]:
     out = ops.paged_attention(q, k, v, pos, table, q_pos, scale=scale)
     with jax.default_matmul_precision("highest"):
         want = ref.paged_attention_ref(
-            q.reshape(LANES, hkv, g, hd), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), pos, table, q_pos,
+            q.reshape(LANES, hkv, g, hd), k, v, pos, table, q_pos,
             jnp.minimum(q_pos // PAGE_SIZE + 1, maxp), scale=scale)
     valid = np.broadcast_to((lens > 0)[:, None, None], out.shape)
     compare("paged_attention", out, want.reshape(LANES, h, hd), valid)
@@ -214,8 +214,8 @@ def _check_kernels(cfg, seed: int) -> list[str]:
     with jax.default_matmul_precision("highest"):
         want = ref.paged_prefill_ref(
             q.reshape(LANES, CHUNK, hkv, g, hd).transpose(0, 2, 1, 3, 4),
-            c_pos, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), pos,
-            table, start, jnp.clip(-(-start // PAGE_SIZE), 0, maxp),
+            c_pos, k, v, pos, table, start,
+            jnp.clip(-(-start // PAGE_SIZE), 0, maxp),
             ck.transpose(0, 2, 1, 3), cv.transpose(0, 2, 1, 3), c_pos,
             scale=scale)
     want = want.transpose(0, 2, 1, 3, 4).reshape(LANES, CHUNK, h, hd)

@@ -34,14 +34,15 @@ def _pad_to(x, axis, mult, value=0.0):
     return jnp.pad(x, widths, constant_values=value)
 
 
-# the scope names the pool's relayout in a device trace; it must not
-# name a kernel, or the trace reduction would count it as one
-@jax.named_scope("kv_layout")
-def _kv_layout(k_pages, v_pages):
-    """The kernels' (P, Hkv, page, hd) view of the pool, hd padded to
-    128."""
-    return (_pad_to(k_pages.transpose(0, 2, 1, 3), 3, 128),
-            _pad_to(v_pages.transpose(0, 2, 1, 3), 3, 128))
+def _stacked(k_pages, v_pages, pos_pages, layer):
+    """The pool as the paged kernels read it: layer-stacked leaves (a
+    single layer's pool gains a unit layer axis, a free reshape) and the
+    layer to read as a (1,) i32 scalar prefetch."""
+    if k_pages.ndim == 4:
+        k_pages, v_pages, pos_pages = k_pages[None], v_pages[None], \
+            pos_pages[None]
+    return (k_pages, v_pages, pos_pages,
+            jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)))
 
 
 def flash_attention(q, k, v, *, scale: float, causal: bool = True,
@@ -67,73 +68,77 @@ def flash_attention(q, k, v, *, scale: float, causal: bool = True,
 
 
 def paged_attention(q, k_pages, v_pages, pos_pages, page_table, q_pos, *,
-                    scale: float, window: int | None = None,
+                    scale: float, window: int | None = None, layer=0,
                     interpret: bool | None = None):
     """Paged single-token decode attention — model layout in/out.
 
-    q (B, H, hd) with H = G * Hkv; k/v_pages (P, page, Hkv, hd) — the
-    pool layout models/attention.py scatters into; pos_pages (P, page)
-    i32 (-1 empty); page_table (B, maxp) i32 garbage-page padded; q_pos
-    (B,) i32.  Pads hd to 128 and the q group to a sublane multiple of
-    8, derives the per-lane visited-page count from q_pos, and hands the
-    kernel the (P, Hkv, page, hd) transpose.  Returns (B, H, hd).
+    q (B, H, hd) with H = G * Hkv; k/v_pages (P, Hkv, hd, page) and
+    pos_pages (P, 1, page) i32 (-1 empty) — the pool as stored
+    (DESIGN.md §8), or one segment's layer-stacked pool with a leading
+    L axis and ``layer`` (an i32 scalar, traced or not) picking the
+    layer, read in place; page_table (B, maxp) i32 garbage-page padded;
+    q_pos (B,) i32.  Pads the q group to a sublane multiple of 8 and
+    derives the per-lane visited-page count from q_pos; hd is not
+    padded.  Returns (B, H, hd).
     """
     interpret = on_cpu() if interpret is None else interpret
     b, h, hd = q.shape
-    ps = k_pages.shape[1]
-    hkv = k_pages.shape[2]
+    ps = k_pages.shape[-1]
+    hkv = k_pages.shape[-3]
     g = h // hkv
     gp = -(-g // 8) * 8
-    qg = q.reshape(b, hkv, g, hd)
-    qg = _pad_to(_pad_to(qg, 3, 128), 2, gp)
-    kt, vt = _kv_layout(k_pages, v_pages)
+    qg = _pad_to(q.reshape(b, hkv, g, hd), 2, gp)
+    k_pages, v_pages, pos_pages, layer = _stacked(k_pages, v_pages,
+                                                  pos_pages, layer)
     q_pos = q_pos.astype(jnp.int32)
     n_used = jnp.minimum(q_pos // ps + 1, page_table.shape[1])
     out = _pa.paged_attention_kernel(
-        qg, kt, vt, pos_pages.astype(jnp.int32),
-        page_table.astype(jnp.int32), q_pos, n_used, scale=scale,
+        qg, k_pages, v_pages, pos_pages.astype(jnp.int32),
+        page_table.astype(jnp.int32), q_pos, n_used, layer, scale=scale,
         window=window, interpret=interpret)
-    return out[:, :, :g, :hd].reshape(b, h, hd)
+    return out[:, :, :g].reshape(b, h, hd)
 
 
 def paged_prefill(q, k_pages, v_pages, pos_pages, page_table, q_pos,
                   chunk_start, ck, cv, c_pos, *, scale: float,
-                  window: int | None = None,
+                  window: int | None = None, layer=0,
                   interpret: bool | None = None):
     """Chunked-prefill attention over the paged pool — model layout.
 
     q (B, C, H, hd) chunk queries with H = G * Hkv and per-row positions
-    q_pos (B, C) i32 (-1 = padded row); k/v_pages (P, page, Hkv, hd) —
-    the pool layout models/attention.py scatters into; pos_pages
-    (P, page) i32; page_table (B, maxp) i32; chunk_start (B,) i32
-    (history clipped to kpos < start); ck/cv (B, C, Hkv, hd) the chunk's
-    own in-flight keys/values at positions c_pos (B, C).  Pads hd to
-    128, the q group to a sublane multiple of 8, and the chunk-key axis
-    to 128, derives the history page count from chunk_start, and hands
-    the kernel the (P, Hkv, page, hd) transpose.  Returns (B, C, H, hd).
+    q_pos (B, C) i32 (-1 = padded row); k/v_pages (P, Hkv, hd, page) and
+    pos_pages (P, 1, page) i32 — the pool as stored, or layer-stacked
+    with ``layer`` picking the layer (as `paged_attention`);
+    page_table (B, maxp) i32; chunk_start (B,) i32 (history clipped to
+    kpos < start); ck/cv (B, C, Hkv, hd) the chunk's own in-flight
+    keys/values at positions c_pos (B, C).  Pads the q group to a
+    sublane multiple of 8 and the chunk-key axis to 128, and derives the
+    history page count from chunk_start; hd is not padded.  Returns
+    (B, C, H, hd).
     """
     interpret = on_cpu() if interpret is None else interpret
     b, c, h, hd = q.shape
-    ps = k_pages.shape[1]
-    hkv = k_pages.shape[2]
+    ps = k_pages.shape[-1]
+    hkv = k_pages.shape[-3]
     g = h // hkv
     gp = -(-g // 8) * 8
     # (B, C, H, hd) -> (B, Hkv, C, G, hd): row c*G + g is query (c, g)
     qg = q.reshape(b, c, hkv, g, hd).transpose(0, 2, 1, 3, 4)
-    qg = _pad_to(_pad_to(qg, 4, 128), 3, gp)
-    qg = qg.reshape(b, hkv, c * gp, hd + (-hd) % 128)
-    kt, vt = _kv_layout(k_pages, v_pages)
+    qg = _pad_to(qg, 3, gp).reshape(b, hkv, c * gp, hd)
+    k_pages, v_pages, pos_pages, layer = _stacked(k_pages, v_pages,
+                                                  pos_pages, layer)
     cp = -(-c // 128) * 128
-    ckt = _pad_to(_pad_to(ck.transpose(0, 2, 1, 3), 3, 128), 2, cp)
-    cvt = _pad_to(_pad_to(cv.transpose(0, 2, 1, 3), 3, 128), 2, cp)
+    ckt = _pad_to(ck.transpose(0, 2, 1, 3), 2, cp)
+    cvt = _pad_to(cv.transpose(0, 2, 1, 3), 2, cp)
     c_pos_p = _pad_to(c_pos.astype(jnp.int32), 1, cp, value=-1)
     chunk_start = chunk_start.astype(jnp.int32)
     n_hist = jnp.clip(-(-chunk_start // ps), 0, page_table.shape[1])
     out = _pp.paged_prefill_kernel(
-        qg, q_pos.astype(jnp.int32), kt, vt, pos_pages.astype(jnp.int32),
-        page_table.astype(jnp.int32), chunk_start, n_hist, ckt, cvt,
-        c_pos_p, scale=scale, window=window, interpret=interpret)
-    out = out.reshape(b, hkv, c, gp, hd + (-hd) % 128)[:, :, :, :g, :hd]
+        qg, q_pos.astype(jnp.int32), k_pages, v_pages,
+        pos_pages.astype(jnp.int32), page_table.astype(jnp.int32),
+        chunk_start, n_hist, ckt, cvt, c_pos_p, layer, scale=scale,
+        window=window, interpret=interpret)
+    out = out.reshape(b, hkv, c, gp, hd)[:, :, :, :g]
     return out.transpose(0, 2, 1, 3, 4).reshape(b, c, h, hd)
 
 

@@ -17,12 +17,16 @@ positions beyond the lane's query position (stale shared-page tails)
 are masked by causality — plus the sliding window if configured.  Pages
 past the lane's used count are skipped entirely with pl.when.
 
-Block shapes: the (group x page_size) score tile wants page_size to be
-a multiple of 128 on real TPUs (lane alignment; q group is padded to a
-sublane multiple by ops.py).  Interpret mode (CPU CI) takes any shape.
-The int32 position pages travel as (P, 1, ps) with (1, 1, ps) blocks:
-Mosaic needs a block's last two dims divisible by (8, 128) or equal to
-the array's, and a unit middle axis satisfies that at any page size.
+The kernel reads the pool in place, in the layout it is stored in
+(DESIGN.md §8): one segment's layer-stacked leaves, ``k/v (L, P, Hkv,
+hd, ps)`` and ``pos (L, P, 1, ps)``, with the layer picked by a scalar
+prefetch.  A page of one head is a (hd, ps) tile with the page-slot
+axis minor: at hd 64 and ps 128 it tiles densely, with no padding of
+hd, and the scores are ``q @ k_page`` and the output ``p @ v_page^T``.
+Block shapes: ps a multiple of 128 on real TPUs (lane alignment; the q
+group is padded to a sublane multiple by ops.py); the position pages'
+(1, ps) blocks satisfy Mosaic through their unit axis.  Interpret mode
+(CPU CI) takes any shape.
 """
 
 from __future__ import annotations
@@ -39,8 +43,8 @@ __all__ = ["paged_attention_kernel"]
 NEG_INF = -1e30
 
 
-def _kernel(table_ref, qpos_ref, nused_ref, q_ref, k_ref, v_ref, pos_ref,
-            o_ref, m_scr, l_scr, acc_scr, *, scale: float,
+def _kernel(table_ref, qpos_ref, nused_ref, layer_ref, q_ref, k_ref, v_ref,
+            pos_ref, o_ref, m_scr, l_scr, acc_scr, *, scale: float,
             window: int | None, hkv: int):
     bh = pl.program_id(0)           # lane * Hkv + kv_head
     j = pl.program_id(1)            # index into the lane's page table
@@ -56,10 +60,10 @@ def _kernel(table_ref, qpos_ref, nused_ref, q_ref, k_ref, v_ref, pos_ref,
     @pl.when(j < nused_ref[lane])
     def _compute():
         q = q_ref[0].astype(jnp.float32)            # (G, hd)
-        k = k_ref[0, 0].astype(jnp.float32)         # (ps, hd)
-        v = v_ref[0, 0].astype(jnp.float32)
-        kpos = pos_ref[0, 0]                        # (ps,) i32
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        k = k_ref[...].astype(jnp.float32)          # (hd, ps)
+        v = v_ref[...].astype(jnp.float32)          # (hd, ps)
+        kpos = pos_ref[0]                           # (ps,) i32
+        s = jax.lax.dot_general(q, k, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         qp = qpos_ref[lane]
         valid = (kpos >= 0) & (kpos <= qp)
@@ -74,7 +78,7 @@ def _kernel(table_ref, qpos_ref, nused_ref, q_ref, k_ref, v_ref, pos_ref,
         p = jnp.where(valid[None, :], p, 0.0)
         l_scr[...] = l_scr[...] * alpha + p.sum(axis=1)
         acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
@@ -88,36 +92,36 @@ def _kernel(table_ref, qpos_ref, nused_ref, q_ref, k_ref, v_ref, pos_ref,
 @functools.partial(jax.jit, static_argnames=("scale", "window",
                                              "interpret"))
 def paged_attention_kernel(q, k_pages, v_pages, pos_pages, page_table,
-                           q_pos, n_used, *, scale: float,
+                           q_pos, n_used, layer, *, scale: float,
                            window: int | None = None,
                            interpret: bool = False):
-    """q (B, Hkv, G, hd); k/v_pages (P, Hkv, ps, hd); pos_pages (P, ps)
-    i32; page_table (B, maxp) i32 (garbage-page padded); q_pos (B,) i32;
-    n_used (B,) i32 pages to visit per lane.  hd % 128 == 0
-    (ops.paged_attention pads).  Returns (B, Hkv, G, hd)."""
+    """q (B, Hkv, G, hd); k/v_pages (L, P, Hkv, hd, ps); pos_pages
+    (L, P, 1, ps) i32; page_table (B, maxp) i32 (garbage-page padded);
+    q_pos (B,) i32; n_used (B,) i32 pages to visit per lane; layer (1,)
+    i32, the layer of the stacked pool to read.  Returns (B, Hkv, G,
+    hd)."""
     b, hkv, g, hd = q.shape
-    ps = k_pages.shape[2]
+    ps = k_pages.shape[-1]
     maxp = page_table.shape[1]
     qf = q.reshape(b * hkv, g, hd)
 
+    def page(bh, j, t, qp, nu, ly):
+        return (ly[0], t[bh // hkv, j], bh % hkv, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b * hkv, maxp),
         in_specs=[
             pl.BlockSpec((1, g, hd),
-                         lambda bh, j, t, qp, nu: (bh, 0, 0)),
-            pl.BlockSpec((1, 1, ps, hd),
-                         lambda bh, j, t, qp, nu, hkv=hkv:
-                         (t[bh // hkv, j], bh % hkv, 0, 0)),
-            pl.BlockSpec((1, 1, ps, hd),
-                         lambda bh, j, t, qp, nu, hkv=hkv:
-                         (t[bh // hkv, j], bh % hkv, 0, 0)),
-            pl.BlockSpec((1, 1, ps),
-                         lambda bh, j, t, qp, nu, hkv=hkv:
-                         (t[bh // hkv, j], 0, 0)),
+                         lambda bh, j, t, qp, nu, ly: (bh, 0, 0)),
+            pl.BlockSpec((None, None, None, hd, ps), page),
+            pl.BlockSpec((None, None, None, hd, ps), page),
+            pl.BlockSpec((None, None, 1, ps),
+                         lambda bh, j, t, qp, nu, ly:
+                         (ly[0], t[bh // hkv, j], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, g, hd),
-                               lambda bh, j, t, qp, nu: (bh, 0, 0)),
+                               lambda bh, j, t, qp, nu, ly: (bh, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g,), jnp.float32),
             pltpu.VMEM((g,), jnp.float32),
@@ -132,5 +136,5 @@ def paged_attention_kernel(q, k_pages, v_pages, pos_pages, page_table,
         out_shape=jax.ShapeDtypeStruct((b * hkv, g, hd), q.dtype),
         interpret=interpret,
     )(page_table, q_pos.astype(jnp.int32), n_used.astype(jnp.int32),
-      qf, k_pages, v_pages, pos_pages[:, None, :])
+      layer.astype(jnp.int32), qf, k_pages, v_pages, pos_pages)
     return out.reshape(b, hkv, g, hd)

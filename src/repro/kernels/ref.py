@@ -39,19 +39,20 @@ def paged_attention_ref(q, k_pages, v_pages, pos_pages, page_table, q_pos,
                         n_used, *, scale: float, window: int | None = None):
     """Paged single-token decode attention (paged_attention.py contract).
 
-    q (B, Hkv, G, hd); k/v_pages (P, Hkv, ps, hd); pos_pages (P, ps) i32
-    (-1 = empty slot); page_table (B, maxp) i32; q_pos (B,) i32;
-    n_used (B,) i32 — table entries at index >= n_used are ignored.
-    Returns (B, Hkv, G, hd); lanes with nothing attendable return zeros.
+    q (B, Hkv, G, hd); k/v_pages (P, Hkv, hd, ps) and pos_pages
+    (P, 1, ps) i32 (-1 = empty slot) — the pool as stored (DESIGN.md
+    §8); page_table (B, maxp) i32; q_pos (B,) i32; n_used (B,) i32 —
+    table entries at index >= n_used are ignored.  Returns (B, Hkv, G,
+    hd); lanes with nothing attendable return zeros.
     """
     b, hkv, g, hd = q.shape
-    ps = k_pages.shape[2]
+    ps = k_pages.shape[-1]
     maxp = page_table.shape[1]
-    k = k_pages[page_table].astype(jnp.float32)     # (B, maxp, Hkv, ps, hd)
+    k = k_pages[page_table].astype(jnp.float32)     # (B, maxp, Hkv, hd, ps)
     v = v_pages[page_table].astype(jnp.float32)
-    kpos = pos_pages[page_table]                    # (B, maxp, ps)
-    k = k.transpose(0, 2, 1, 3, 4).reshape(b, hkv, maxp * ps, hd)
-    v = v.transpose(0, 2, 1, 3, 4).reshape(b, hkv, maxp * ps, hd)
+    kpos = pos_pages[page_table][:, :, 0]           # (B, maxp, ps)
+    k = k.transpose(0, 2, 1, 4, 3).reshape(b, hkv, maxp * ps, hd)
+    v = v.transpose(0, 2, 1, 4, 3).reshape(b, hkv, maxp * ps, hd)
     valid = (kpos >= 0) & (kpos <= q_pos[:, None, None])
     if window is not None:
         valid &= kpos > (q_pos[:, None, None] - window)
@@ -75,8 +76,9 @@ def paged_prefill_ref(q, q_pos, k_pages, v_pages, pos_pages, page_table,
     contract).
 
     q (B, Hkv, C, G, hd) chunk queries; q_pos (B, C) i32 (-1 = padded
-    row, returns zeros); k/v_pages (P, Hkv, ps, hd); pos_pages (P, ps)
-    i32 (-1 empty); page_table (B, maxp) i32; chunk_start (B,) i32 —
+    row, returns zeros); k/v_pages (P, Hkv, hd, ps) and pos_pages
+    (P, 1, ps) i32 (-1 empty), as stored; page_table (B, maxp) i32;
+    chunk_start (B,) i32 —
     pool history is clipped to kpos < start (the chunk's own positions
     come from the in-flight block, even if already scattered);
     n_hist (B,) i32 — table entries at index >= n_hist are ignored;
@@ -85,13 +87,13 @@ def paged_prefill_ref(q, q_pos, k_pages, v_pages, pos_pages, page_table,
     Returns (B, Hkv, C, G, hd).
     """
     b, hkv, c, g, hd = q.shape
-    ps = k_pages.shape[2]
+    ps = k_pages.shape[-1]
     maxp = page_table.shape[1]
-    kh = k_pages[page_table].astype(jnp.float32)    # (B, maxp, Hkv, ps, hd)
+    kh = k_pages[page_table].astype(jnp.float32)    # (B, maxp, Hkv, hd, ps)
     vh = v_pages[page_table].astype(jnp.float32)
     kpos = pos_pages[page_table].reshape(b, maxp * ps)
-    kh = kh.transpose(0, 2, 1, 3, 4).reshape(b, hkv, maxp * ps, hd)
-    vh = vh.transpose(0, 2, 1, 3, 4).reshape(b, hkv, maxp * ps, hd)
+    kh = kh.transpose(0, 2, 1, 4, 3).reshape(b, hkv, maxp * ps, hd)
+    vh = vh.transpose(0, 2, 1, 4, 3).reshape(b, hkv, maxp * ps, hd)
     page_ok = jnp.repeat(jnp.arange(maxp)[None, :] < n_hist[:, None], ps,
                          axis=1)                    # (B, maxp*ps)
     hist_ok = (kpos >= 0) & (kpos < chunk_start[:, None]) & page_ok

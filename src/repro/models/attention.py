@@ -8,15 +8,25 @@ Ring cache layout (fixed shapes — TPU-friendly):
   MLA:  c_kv: (B, C, lora); k_rope: (B, C, rope_dim); pos: (B, C).
 C = min(seq_len, window) — sliding windows bound the decode cache.
 
-Paged layout (serving.kvpool): the SAME leaf names with the lane axis
-replaced by a global page pool — ``k, v: (P, page, Hkv, hd)``, ``pos:
-(P, page)`` — plus a per-lane `PagedKV` handle carrying the page table
-and this token's (page, slot) write target.  Page 0 is the reserved
-garbage sink: lanes masked out by ``write_mask`` (early-exited or
-unoccupied) write their K/V there with position -1, so gathered garbage
-is never attended; unused page-table entries also point at page 0.  The
-holes a masked write leaves behind are therefore hidden by the SAME
-stored-position mask the ring path uses.
+Paged layout (serving.kvpool, DESIGN.md §8): the SAME leaf names with
+the lane axis replaced by a global page pool and the page-slot axis
+minor — ``k, v: (P, Hkv, hd, page)``, ``pos: (P, 1, page)`` (each ring
+leaf ``(B, C, *row)`` becomes ``(P, *row or (1,), page)``,
+`pool_cache_defs`) — plus a per-lane `PagedKV` handle carrying the page
+table and this token's (page, slot) write target.  This is the layout
+the paged kernels read, so the pool is never relaid: at hd 64 and a
+page of 128 a head's page is a dense (64, 128) tile, where an hd-minor
+pool would pad hd to 128 lanes.  Writes are in place: `pool_write`
+reads the few pages a step's rows land in, sets those slots and writes
+the pages back (a whole-page window, which XLA scatters in this layout
+without relaying the pool).  In serving, the pool is one segment's
+layer-stacked leaves ``(L, P, ...)``, carried whole through the layer
+loop, and each layer reads and writes its own layer by index.  Page 0
+is the reserved garbage sink: unused page-table entries point at it
+and it never holds a position (-1 throughout), so gathered garbage is
+never attended; lanes masked out by ``write_mask`` (early-exited or
+unoccupied) write nothing, and the holes they leave behind are hidden
+by the SAME stored-position mask the ring path uses.
 
 The einsum/jnp path is what the multi-pod dry-run lowers (XLA fuses it and
 GSPMD shards it); the Pallas flash kernel (repro.kernels.flash_attention)
@@ -39,8 +49,9 @@ from repro.models.config import AttnConfig
 from repro.models.param import ParamDef
 
 __all__ = ["attn_defs", "attn_forward", "attn_decode",
-           "attn_prefill_chunk", "init_cache_defs", "PagedKV",
-           "PrefillChunk", "paged_kernel"]
+           "attn_prefill_chunk", "init_cache_defs", "pool_cache_defs",
+           "pool_targets", "pool_write", "PagedKV", "PrefillChunk",
+           "paged_kernel"]
 
 # must agree with serving.kvpool.alloc.GARBAGE_PAGE (kept as a literal so
 # the model layer never imports the serving layer)
@@ -149,6 +160,90 @@ def init_cache_defs(cfg: AttnConfig, batch: int, cache_len: int) -> dict:
         out["k_s"] = ((batch, cache_len, cfg.n_kv_heads), jnp.bfloat16)
         out["v_s"] = ((batch, cache_len, cfg.n_kv_heads), jnp.bfloat16)
     return out
+
+
+def pool_cache_defs(cfg: AttnConfig, n_pages: int, page_size: int) -> dict:
+    """One layer's paged-pool spec (DESIGN.md §8): each leaf of the ring
+    cache ``(B, C, *row)`` becomes ``(P, *row, page)``, with a unit row
+    for the per-position leaves (``pos``, MLA's int8 scales) — the
+    page-slot axis minor, the layout the paged kernels read."""
+    return {name: ((n_pages,) + (shape[2:] or (1,)) + (page_size,), dt)
+            for name, (shape, dt) in init_cache_defs(
+                cfg, n_pages, page_size).items()}
+
+
+def pool_targets(page, off, live, n_win: int, ps: int):
+    """The pages of one pool write.  A lane's rows (B, C) hold
+    consecutive positions: row i lands in slot ``off + i`` of the run of
+    ``n_win`` pages its rows span (``off`` (B,) the first row's slot),
+    in page ``page[:, i]``; ``live`` marks the rows that land at all.
+    Returns (pages (B, n_win) i32, the pages of the run, garbage where
+    no row lands; off; live) — the targets `pool_write` takes."""
+    b, c = page.shape
+    win = jnp.where(live, (off[:, None] + jnp.arange(c)[None]) // ps,
+                    n_win)                     # dead rows: a dropped column
+    pages = jnp.full((b, n_win + 1), _GARBAGE_PAGE, jnp.int32).at[
+        jnp.arange(b)[:, None], win].max(
+            jnp.where(live, page, _GARBAGE_PAGE))
+    return pages[:, :n_win], off, live
+
+
+# the scope names the pool's writes in a device trace; it must not name
+# a kernel, or the trace reduction would count it as one
+@jax.named_scope("page_write")
+def pool_write(leaf, layer, targets, rows):
+    """Write rows (B, C, *row) into ``layer`` of a layer-stacked pool
+    leaf (L, P, *row, ps) in place: read the pages `pool_targets` names,
+    set the live rows' slots, write the pages back.  Only whole pages
+    move (windows XLA scatters without relaying the pool), and only the
+    few a step writes.  A single row is broadcast over its page and
+    selected by slot; consecutive rows are shifted to their slots as a
+    block, one slice per lane (no per-row gather)."""
+    pages, off, live = targets
+    b, n_win = pages.shape
+    c, ps = rows.shape[1], leaf.shape[-1]
+    old = leaf[layer, pages]                              # (B, J, *row, ps)
+    r = rows.reshape(b, c, -1).astype(leaf.dtype)
+    if c == 1:
+        hit = (jnp.arange(ps)[None] == off[:, None]) & live
+        new = r.reshape(old.shape[:-1] + (1,))
+    else:
+        n = n_win * ps
+
+        def shift(x, o):                 # x[ps + i] -> slot o + i of run
+            return jax.lax.dynamic_slice_in_dim(x, ps - o, n)
+
+        pad = ((0, 0), (ps, n - c))
+        new = jax.vmap(shift)(jnp.pad(r, pad + ((0, 0),)), off)
+        hit = jax.vmap(shift)(jnp.pad(live, pad), off)
+        new = jnp.moveaxis(new.reshape(b, n_win, ps, -1), 2, -1).reshape(
+            old.shape)
+    hit = hit.reshape((b, n_win) + (1,) * (old.ndim - 3) + (ps,))
+    return leaf.at[layer, pages].set(jnp.where(hit, new, old))
+
+
+def _pool_commit(pool: dict, layer, targets, rows: dict) -> dict:
+    """`pool_write` of every leaf that has rows."""
+    out = dict(pool)
+    for name, r in rows.items():
+        out[name] = pool_write(pool[name], layer, targets, r)
+    return out
+
+
+def _pool_gather(leaf, layer, table):
+    """The lanes' pages of ``layer`` as rows: (B, maxp * ps, *row)."""
+    g = leaf[layer, table]                         # (B, maxp, *row, ps)
+    g = jnp.moveaxis(g, -1, 2)
+    return g.reshape((g.shape[0], -1) + g.shape[3:])
+
+
+def _decode_targets(paged: "PagedKV", write_mask, ps: int):
+    """Targets of one decode token per lane: the lane's (page, slot),
+    or nothing for lanes masked out by ``write_mask``."""
+    wp = paged.write_page[:, None]
+    live = jnp.ones_like(wp, bool) if write_mask is None \
+        else write_mask[:, None]
+    return pool_targets(wp, paged.write_slot, live, 1, ps)
 
 
 # --------------------------------------------------------------------------
@@ -353,79 +448,74 @@ def _gqa_qkv_decode(p: dict, x: jax.Array, pos: jax.Array, cfg: AttnConfig,
     return rope(q, cos, sin), rope(k, cos, sin), v
 
 
-def _paged_targets(paged: PagedKV, pos: jax.Array, write_mask):
-    """(write_page, write_slot, stored_pos) with masked lanes redirected
-    to the garbage page / position -1 — the paged equivalent of the
-    engine's per-lane masked ring writes."""
-    wp = paged.write_page
-    pw = pos.astype(jnp.int32)
-    if write_mask is not None:
-        wp = jnp.where(write_mask, wp, _GARBAGE_PAGE)
-        pw = jnp.where(write_mask, pw, -1)
-    return wp, paged.write_slot, pw
-
-
-def _gqa_decode_paged(p, x, cache, pos, cfg: AttnConfig, eps,
-                      paged: PagedKV, write_mask):
-    """One-token GQA decode against the paged pool: scatter the new
-    token's K/V into the lane's (page, slot) write target, then attend
-    over the page-table gather of the pool."""
-    b = x.shape[0]
-    ps = cache["k"].shape[1]
-    q, k, v = _gqa_qkv_decode(p, x, pos, cfg, eps)
-    wp, ws, pw = _paged_targets(paged, pos, write_mask)
-    new_cache = dict(cache)
-    if "k_s" in cache:  # int8 pool path (models.quant)
-        from repro.models.quant import dequantize_rows, quantize_rows
-        kq, ks = quantize_rows(k[:, 0])
-        vq, vs = quantize_rows(v[:, 0])
-        new_cache["k"] = cache["k"].at[wp, ws].set(kq)
-        new_cache["v"] = cache["v"].at[wp, ws].set(vq)
-        new_cache["k_s"] = cache["k_s"].at[wp, ws].set(ks)
-        new_cache["v_s"] = cache["v_s"].at[wp, ws].set(vs)
+def _kv_rows(pool: dict, k, v, pos) -> dict:
+    """The rows a write stores: k/v (B, C, Hkv, hd) in the pool's dtype
+    (int8 with per-(position, head) scales under models.quant) and the
+    positions (B, C)."""
+    if "k_s" in pool:
+        from repro.models.quant import quantize_rows
+        kq, ks = quantize_rows(k)
+        vq, vs = quantize_rows(v)
+        rows = {"k": kq, "v": vq, "k_s": ks, "v_s": vs}
     else:
-        new_cache["k"] = cache["k"].at[wp, ws].set(
-            k[:, 0].astype(cache["k"].dtype))
-        new_cache["v"] = cache["v"].at[wp, ws].set(
-            v[:, 0].astype(cache["v"].dtype))
-    new_cache["pos"] = cache["pos"].at[wp, ws].set(pw)
+        rows = {"k": k.astype(pool["k"].dtype),
+                "v": v.astype(pool["v"].dtype)}
+    rows["pos"] = pos.astype(jnp.int32)
+    return rows
+
+
+def _kv_history(pool: dict, layer, table, dtype):
+    """The jnp gather path's view of the lanes' pages: k/v (B, T, Hkv,
+    hd) in ``dtype`` and positions (B, T), T = maxp * ps."""
+    k = _pool_gather(pool["k"], layer, table)
+    v = _pool_gather(pool["v"], layer, table)
+    if "k_s" in pool:
+        from repro.models.quant import dequantize_rows
+        k = dequantize_rows(k, _pool_gather(pool["k_s"], layer, table),
+                            dtype)
+        v = dequantize_rows(v, _pool_gather(pool["v_s"], layer, table),
+                            dtype)
+    return (k.astype(dtype), v.astype(dtype),
+            _pool_gather(pool["pos"], layer, table)[..., 0])
+
+
+def _gqa_decode_paged(p, x, pool, pos, cfg: AttnConfig, eps,
+                      paged: PagedKV, write_mask, layer):
+    """One-token GQA decode against the paged pool: write the new
+    token's K/V into the lane's (page, slot) target of ``layer``, then
+    attend over the lane's pages of that layer."""
+    b = x.shape[0]
+    q, k, v = _gqa_qkv_decode(p, x, pos, cfg, eps)
+    new_pool = _pool_commit(
+        pool, layer, _decode_targets(paged, write_mask, pool["k"].shape[-1]),
+        _kv_rows(pool, k, v, pos[:, None]))
 
     table = paged.page_table                                  # (B, maxp)
     scale = cfg.softmax_scale or 1.0 / math.sqrt(cfg.head_dim)
-    if _PAGED_KERNEL.get() and "k_s" not in cache:
+    if _PAGED_KERNEL.get() and "k_s" not in pool:
         from repro.kernels import ops as kops
         out = kops.paged_attention(
-            q[:, 0], new_cache["k"], new_cache["v"], new_cache["pos"],
-            table, pos.astype(jnp.int32), scale=scale, window=cfg.window)
+            q[:, 0], new_pool["k"], new_pool["v"], new_pool["pos"],
+            table, pos.astype(jnp.int32), scale=scale, window=cfg.window,
+            layer=layer)
         out = out[:, None]                                    # (B,1,H,hd)
     else:
-        if "k_s" in cache:
-            from repro.models.quant import dequantize_rows
-            k_full = dequantize_rows(new_cache["k"][table],
-                                     new_cache["k_s"][table], q.dtype)
-            v_full = dequantize_rows(new_cache["v"][table],
-                                     new_cache["v_s"][table], q.dtype)
-        else:
-            k_full = new_cache["k"][table].astype(q.dtype)
-            v_full = new_cache["v"][table].astype(q.dtype)
-        c = table.shape[1] * ps
-        k_full = k_full.reshape(b, c, cfg.n_kv_heads, cfg.head_dim)
-        v_full = v_full.reshape(b, c, cfg.n_kv_heads, -1)
-        pos_full = new_cache["pos"][table].reshape(b, c)
+        k_full, v_full, pos_full = _kv_history(new_pool, layer, table,
+                                               q.dtype)
         mask = causal_mask(pos[:, None], pos_full, cfg.window)
         mask &= pos_full[:, None, :] >= 0
         out = _sdpa(q, k_full, v_full, mask, scale)
     y = out.reshape(b, 1, -1) @ p["wo"]
-    return y, new_cache
+    return y, new_pool
 
 
-def attn_prefill_chunk(p: dict, x: jax.Array, cache: dict,
+def attn_prefill_chunk(p: dict, x: jax.Array, pool: dict,
                        cfg: AttnConfig, eps: float, table: jax.Array,
-                       chunk: PrefillChunk):
-    """One prefill CHUNK against the paged pool (DESIGN.md §9): compute
-    the chunk's q/k/v, scatter K/V into the per-token (page, slot)
-    targets, then attend over the lane's page-table history (committed
-    by earlier chunks — or shared prefix pages, which is why
+                       chunk: PrefillChunk, layer=0):
+    """One prefill CHUNK against ``layer`` of the paged pool (DESIGN.md
+    §9): compute the chunk's q/k/v, write K/V into the per-token (page,
+    slot) targets, then attend over the lane's page-table history
+    (committed by earlier chunks — or shared prefix pages, which is why
     prefix-cache hits can skip their chunks entirely) PLUS the chunk's
     own in-flight keys, causally.
 
@@ -434,18 +524,19 @@ def attn_prefill_chunk(p: dict, x: jax.Array, cache: dict,
     then computes exactly what `attn_forward` computes, so the
     stop-the-world admission path stays the bit-reference.  History
     reads are clipped to ``kpos < chunk.start`` so the chunk's own
-    just-scattered positions are attended exactly once (in-flight).
+    just-written positions are attended exactly once (in-flight).
 
-    x (B, C, D); table (B, maxp) i32 page table; returns
-    (y (B, C, D), new_cache).  MLA segments are not yet chunkable —
-    serve them through the stop-the-world admission path.
+    x (B, C, D); pool: a layer-stacked pool (L, P, ...) whose ``layer``
+    this is; table (B, maxp) i32 page table; returns (y (B, C, D),
+    new_pool).  MLA segments are not yet chunkable — serve them through
+    the stop-the-world admission path.
     """
     if cfg.mla is not None:
         raise NotImplementedError(
             "chunked prefill supports GQA attention only; MLA segments "
             "must admit through the whole-prompt prefill path")
     b, c, _ = x.shape
-    ps = cache["k"].shape[1]
+    ps = pool["k"].shape[-1]
     rpos = jnp.maximum(chunk.pos, 0)          # rope of pad rows: masked
     q = _split_heads(x @ p["wq"], cfg.n_heads, cfg.head_dim)
     k = _split_heads(x @ p["wk"], cfg.n_kv_heads, cfg.head_dim)
@@ -457,51 +548,27 @@ def attn_prefill_chunk(p: dict, x: jax.Array, cache: dict,
     q = rope(q, cos, sin)
     k = rope(k, cos, sin)
 
-    # scatter targets: prefix-cache hits / pad rows / inactive lanes are
-    # redirected to the garbage sink with stored position -1 (the paged
-    # analogue of the engine's masked ring writes)
+    # write targets: prefix-cache hits / pad rows / inactive lanes write
+    # nothing; a chunk's rows span at most n_win of its lane's pages
     live = chunk.active[:, None] & (chunk.pos >= 0) \
         & (chunk.dest_page != _GARBAGE_PAGE)
-    dp = jnp.where(live, chunk.dest_page, _GARBAGE_PAGE)
-    pw = jnp.where(live, chunk.pos, -1)
-    ds = chunk.dest_slot
-    new_cache = dict(cache)
-    if "k_s" in cache:  # int8 pool path (models.quant)
-        from repro.models.quant import dequantize_rows, quantize_rows
-        kq, ks = quantize_rows(k)
-        vq, vs = quantize_rows(v)
-        new_cache["k"] = cache["k"].at[dp, ds].set(kq)
-        new_cache["v"] = cache["v"].at[dp, ds].set(vq)
-        new_cache["k_s"] = cache["k_s"].at[dp, ds].set(ks)
-        new_cache["v_s"] = cache["v_s"].at[dp, ds].set(vs)
-    else:
-        new_cache["k"] = cache["k"].at[dp, ds].set(
-            k.astype(cache["k"].dtype))
-        new_cache["v"] = cache["v"].at[dp, ds].set(
-            v.astype(cache["v"].dtype))
-    new_cache["pos"] = cache["pos"].at[dp, ds].set(pw)
+    n_win = (c + ps - 2) // ps + 1
+    new_pool = _pool_commit(
+        pool, layer,
+        pool_targets(chunk.dest_page, chunk.dest_slot[:, 0], live, n_win,
+                     ps),
+        _kv_rows(pool, k, v, chunk.pos))
 
     scale = cfg.softmax_scale or 1.0 / math.sqrt(cfg.head_dim)
-    if _PAGED_KERNEL.get() and "k_s" not in cache:
+    if _PAGED_KERNEL.get() and "k_s" not in pool:
         from repro.kernels import ops as kops
         out = kops.paged_prefill(
-            q, new_cache["k"], new_cache["v"], new_cache["pos"], table,
+            q, new_pool["k"], new_pool["v"], new_pool["pos"], table,
             chunk.pos, chunk.start, k, v, chunk.pos, scale=scale,
-            window=cfg.window)
+            window=cfg.window, layer=layer)
     else:
-        maxp = table.shape[1]
-        if "k_s" in cache:
-            k_hist = dequantize_rows(new_cache["k"][table],
-                                     new_cache["k_s"][table], q.dtype)
-            v_hist = dequantize_rows(new_cache["v"][table],
-                                     new_cache["v_s"][table], q.dtype)
-        else:
-            k_hist = new_cache["k"][table].astype(q.dtype)
-            v_hist = new_cache["v"][table].astype(q.dtype)
-        t = maxp * ps
-        k_hist = k_hist.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
-        v_hist = v_hist.reshape(b, t, cfg.n_kv_heads, -1)
-        pos_hist = new_cache["pos"][table].reshape(b, t)
+        k_hist, v_hist, pos_hist = _kv_history(new_pool, layer, table,
+                                               q.dtype)
         hist_ok = (pos_hist >= 0) & (pos_hist < chunk.start[:, None])
         k_all = jnp.concatenate([k_hist, k], axis=1)
         v_all = jnp.concatenate([v_hist, v], axis=1)
@@ -511,32 +578,35 @@ def attn_prefill_chunk(p: dict, x: jax.Array, cache: dict,
             & ok_all[:, None, :]
         out = _sdpa(q, k_all, v_all, mask, scale)
     y = out.reshape(b, c, -1) @ p["wo"]
-    return y, new_cache
+    return y, new_pool
 
 
 def attn_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
                 cfg: AttnConfig, eps: float = 1e-5,
-                paged: PagedKV | None = None, write_mask=None):
+                paged: PagedKV | None = None, write_mask=None, layer=0):
     """One-token decode against the ring-buffer cache, or — when a
     `PagedKV` handle is given — against the paged KV pool.
 
     Args:
       x: (B, 1, D) current token activations.
-      cache: ring {"k","v": (B,C,Hkv,hd), "pos": (B,C)} or paged pool
-        {"k","v": (P,page,Hkv,hd), "pos": (P,page)}.
+      cache: ring {"k","v": (B,C,Hkv,hd), "pos": (B,C)} or a
+        layer-stacked paged pool {"k","v": (L,P,Hkv,hd,page), "pos":
+        (L,P,1,page)}, of which this is ``layer``.
       pos: (B,) absolute position of the new token.
       paged: page table + this token's write target (paged mode only).
       write_mask: (B,) lanes whose write should land (paged mode; masked
-        lanes are redirected to the garbage page — ring callers mask via
-        the engine's `_mask_lane_writes` instead).
+        lanes write nothing — ring callers mask via the engine's
+        `_mask_lane_writes` instead).
+      layer: the pool's layer (paged mode; an i32 scalar, traced or not).
 
-    Returns (y, new_cache).
+    Returns (y, new_cache) — in paged mode the whole stacked pool.
     """
     if cfg.mla is not None:
-        return _mla_decode(p, x, cache, pos, cfg, eps, paged, write_mask)
+        return _mla_decode(p, x, cache, pos, cfg, eps, paged, write_mask,
+                           layer)
     if paged is not None:
         return _gqa_decode_paged(p, x, cache, pos, cfg, eps, paged,
-                                 write_mask)
+                                 write_mask, layer)
     b, _, d = x.shape
     c = cache["k"].shape[1]
     q, k, v = _gqa_qkv_decode(p, x, pos, cfg, eps)
@@ -618,11 +688,11 @@ def _mla_forward(p, x, positions, cfg: AttnConfig, eps):
 
 
 def _mla_decode(p, x, cache, pos, cfg: AttnConfig, eps,
-                paged: PagedKV | None = None, write_mask=None):
+                paged: PagedKV | None = None, write_mask=None, layer=0):
     """Absorbed-matmul MLA decode: attention runs in the compressed
     kv_lora space — the cache stays (B, C, lora + rope) (ring) or
-    (P, page, lora + rope) (paged), which is the whole point of MLA
-    (DESIGN.md §4)."""
+    (P, lora + rope, page) per layer (paged), which is the whole point
+    of MLA (DESIGN.md §4)."""
     m = cfg.mla
     b = x.shape[0]
     h = cfg.n_heads
@@ -634,54 +704,47 @@ def _mla_decode(p, x, cache, pos, cfg: AttnConfig, eps,
     q_rope = rope(q_rope, cos, sin)
     k_rope_new = rope(k_rope_new[..., None, :], cos, sin)[..., 0, :]
 
-    if paged is not None:
-        widx = _paged_targets(paged, pos, write_mask)
-    else:
-        c = cache["c_kv"].shape[1]
-        widx = (jnp.arange(b), (pos % c).astype(jnp.int32),
-                pos.astype(jnp.int32))
-    wa, wb, pw = widx
-    new_cache = dict(cache)
-    if "c_kv_s" in cache:  # int8 latent cache (models.quant)
+    int8 = "c_kv_s" in cache
+    if int8:  # int8 latent cache (models.quant)
         from repro.models.quant import dequantize_rows, quantize_rows
         cq, cs = quantize_rows(c_new[:, 0])
         rq, rs = quantize_rows(k_rope_new[:, 0])
-        new_cache["c_kv"] = cache["c_kv"].at[wa, wb].set(cq)
-        new_cache["c_kv_s"] = cache["c_kv_s"].at[wa, wb].set(cs)
-        new_cache["k_rope"] = cache["k_rope"].at[wa, wb].set(rq)
-        new_cache["k_rope_s"] = cache["k_rope_s"].at[wa, wb].set(rs)
-        if paged is None:
-            ckv = dequantize_rows(new_cache["c_kv"], new_cache["c_kv_s"])
-            krope = dequantize_rows(new_cache["k_rope"],
-                                    new_cache["k_rope_s"])
-        else:
-            ckv = krope = None   # dequantized after the page gather
+        rows = {"c_kv": cq, "c_kv_s": cs, "k_rope": rq, "k_rope_s": rs}
     else:
-        ckv = cache["c_kv"].at[wa, wb].set(
-            c_new[:, 0].astype(cache["c_kv"].dtype))
-        krope = cache["k_rope"].at[wa, wb].set(
-            k_rope_new[:, 0].astype(cache["k_rope"].dtype))
-        new_cache["c_kv"] = ckv
-        new_cache["k_rope"] = krope
-    new_pos = cache["pos"].at[wa, wb].set(pw)
-    new_cache["pos"] = new_pos
+        rows = {"c_kv": c_new[:, 0].astype(cache["c_kv"].dtype),
+                "k_rope": k_rope_new[:, 0].astype(cache["k_rope"].dtype)}
     if paged is not None:
-        # page-table gather back to the per-lane (B, C, ...) layout the
+        # write the row into ``layer`` of the pool, then gather the
+        # lanes' pages back to the per-lane (B, C, ...) layout the
         # absorbed-matmul score path below consumes unchanged; int8
         # pools gather the lane's pages FIRST, then dequantize only
         # those (never the whole pool)
+        rows["pos"] = pos.astype(jnp.int32)
+        new_cache = _pool_commit(
+            cache, layer,
+            _decode_targets(paged, write_mask, cache["c_kv"].shape[-1]),
+            {n: r[:, None] for n, r in rows.items()})
         table = paged.page_table
-        c = table.shape[1] * cache["c_kv"].shape[1]
-        if "c_kv_s" in cache:
-            from repro.models.quant import dequantize_rows as _deq
-            ckv = _deq(new_cache["c_kv"][table],
-                       new_cache["c_kv_s"][table]).reshape(b, c, -1)
-            krope = _deq(new_cache["k_rope"][table],
-                         new_cache["k_rope_s"][table]).reshape(b, c, -1)
-        else:
-            ckv = ckv[table].reshape(b, c, -1)
-            krope = krope[table].reshape(b, c, -1)
-        new_pos = new_pos[table].reshape(b, c)
+        ckv = _pool_gather(new_cache["c_kv"], layer, table)
+        krope = _pool_gather(new_cache["k_rope"], layer, table)
+        if int8:
+            ckv = dequantize_rows(
+                ckv, _pool_gather(new_cache["c_kv_s"], layer, table)[..., 0])
+            krope = dequantize_rows(
+                krope,
+                _pool_gather(new_cache["k_rope_s"], layer, table)[..., 0])
+        new_pos = _pool_gather(new_cache["pos"], layer, table)[..., 0]
+    else:
+        c = cache["c_kv"].shape[1]
+        bidx, slot = jnp.arange(b), (pos % c).astype(jnp.int32)
+        new_cache = {name: cache[name].at[bidx, slot].set(r)
+                     for name, r in rows.items()}
+        new_pos = cache["pos"].at[bidx, slot].set(pos.astype(jnp.int32))
+        new_cache["pos"] = new_pos
+        ckv, krope = new_cache["c_kv"], new_cache["k_rope"]
+        if int8:
+            ckv = dequantize_rows(ckv, new_cache["c_kv_s"])
+            krope = dequantize_rows(krope, new_cache["k_rope_s"])
 
     # Absorb W_uk into q: q_c[b,h,r] = sum_n q_nope[b,h,n] W_uk[r, h, n]
     w_uk = p["w_uk"].reshape(m.kv_lora_rank, h, m.qk_nope_head_dim)

@@ -86,12 +86,14 @@ def block_forward(p: dict, x: jax.Array, positions: jax.Array,
 
 def block_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
                  cfg: BlockConfig, eps: float = 1e-5,
-                 paged=None, write_mask=None):
+                 paged=None, write_mask=None, layer=0):
     """One-token step.  x (B,1,D); returns (y, new_cache, aux).
 
     ``paged``/``write_mask`` switch the attention cache to the paged KV
-    pool (attention.PagedKV); SSM state stays lane-indexed either way —
-    its per-lane masking is the engine's job.
+    pool (attention.PagedKV): ``cache["attn"]`` is then the segment's
+    layer-stacked pool, of which this block is ``layer``.  SSM state
+    stays lane-indexed either way — its per-lane masking is the
+    engine's job.
     """
     aux: dict = {}
     xn = rms_norm(p["norm1"], x, eps)
@@ -99,14 +101,14 @@ def block_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
     if cfg.mixer == "attn":
         mix, new_cache["attn"] = attention.attn_decode(
             p["attn"], xn, cache["attn"], pos, cfg.attn, eps,
-            paged=paged, write_mask=write_mask)
+            paged=paged, write_mask=write_mask, layer=layer)
     elif cfg.mixer == "ssm":
         mix, new_cache["ssm"] = ssm_lib.ssm_decode(
             p["ssm"], xn, cache["ssm"], cfg.ssm, eps)
     else:
         ya, new_cache["attn"] = attention.attn_decode(
             p["attn"], xn, cache["attn"], pos, cfg.attn, eps,
-            paged=paged, write_mask=write_mask)
+            paged=paged, write_mask=write_mask, layer=layer)
         ys, new_cache["ssm"] = ssm_lib.ssm_decode(
             p["ssm"], xn, cache["ssm"], cfg.ssm, eps)
         mix = 0.5 * (rms_norm(p["attn_out_norm"], ya, eps)
@@ -124,9 +126,10 @@ def block_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
 
 def block_prefill_chunk(p: dict, x: jax.Array, cache: dict,
                         cfg: BlockConfig, eps: float, table: jax.Array,
-                        chunk) -> tuple[jax.Array, dict]:
-    """One prefill CHUNK through a block against the paged pool
-    (DESIGN.md §9).  x (B, C, D); returns (y, new_cache).  Only
+                        chunk, layer=0) -> tuple[jax.Array, dict]:
+    """One prefill CHUNK through a block against ``layer`` of the
+    segment's layer-stacked paged pool (DESIGN.md §9).  x (B, C, D);
+    returns (y, new_cache).  Only
     attention mixers are chunkable — SSM state is inherently sequential
     over the whole prompt, so ssm/hybrid models admit through the
     stop-the-world prefill path (gated at EngineStepper construction).
@@ -137,7 +140,7 @@ def block_prefill_chunk(p: dict, x: jax.Array, cache: dict,
             f"{cfg.mixer!r}")
     xn = rms_norm(p["norm1"], x, eps)
     mix, new_attn = attention.attn_prefill_chunk(
-        p["attn"], xn, cache["attn"], cfg.attn, eps, table, chunk)
+        p["attn"], xn, cache["attn"], cfg.attn, eps, table, chunk, layer)
     x = x + mix
     if cfg.mlp == "dense":
         x = x + mlp_lib.mlp_forward(p["mlp"], rms_norm(p["norm2"], x, eps),

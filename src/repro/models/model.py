@@ -23,7 +23,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.models import blocks
+from repro.models import attention, blocks
 from repro.models.common import embed_def, rms_norm, rms_norm_def
 from repro.models.config import ModelConfig, Segment
 from repro.models.param import ParamDef
@@ -244,6 +244,50 @@ def prefill(params, cfg: ModelConfig, batch: dict, cache_len: int, *,
 # Decode
 # --------------------------------------------------------------------------
 
+def _layer_loop(body, x, p_seg, cache_seg, n_layers: int, pooled: bool,
+                unroll: bool = False):
+    """Run ``body(h, p_layer, layer_cache, li) -> (h, new_layer_cache)``
+    over a segment's layers.  Lane-indexed leaves are sliced per layer
+    and restacked; with ``pooled`` the paged pool (``"attn"``) stays
+    whole in the loop's carry instead — each layer writes its rows into
+    it in place and reads its own layer by index, so no layer's pool is
+    ever sliced out or restacked (DESIGN.md §8)."""
+    pool = cache_seg.get("attn") if pooled else None
+    laned = {k: v for k, v in cache_seg.items()
+             if not (pooled and k == "attn")}
+
+    def one(h, pool, p_layer, li, lane_c):
+        c = dict(lane_c)
+        if pool is not None:
+            c["attn"] = pool
+        h, nc = body(h, p_layer, c, li)
+        nc = dict(nc)
+        return h, (nc.pop("attn") if pool is not None else None), nc
+
+    if unroll:
+        outs = []
+        for li in range(n_layers):
+            def pick(a, li=li):
+                return a[li]
+            x, pool, nc = one(x, pool, jax.tree.map(pick, p_seg), li,
+                              jax.tree.map(pick, laned))
+            outs.append(nc)
+        new = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+    else:
+        def step(carry, xs):
+            h, pool = carry
+            h, pool, nc = one(h, pool, *xs)
+            return (h, pool), nc
+
+        (x, pool), new = jax.lax.scan(
+            step, (x, pool),
+            (p_seg, jnp.arange(n_layers, dtype=jnp.int32), laned))
+    new = dict(new)
+    if pool is not None:
+        new["attn"] = pool
+    return x, new
+
+
 def decode_segment(params, cfg: ModelConfig, si: int, x: jax.Array,
                    cache_seg, pos: jax.Array, paged=None, write_mask=None,
                    readout_scope: str | None = None):
@@ -258,28 +302,16 @@ def decode_segment(params, cfg: ModelConfig, si: int, x: jax.Array,
     layers at the paged KV pool; the per-lane page table and write
     target are shared by every layer (page ids are global)."""
     seg = cfg.segments[si]
-    p_seg = params["segments"][si]["blocks"]
 
-    if _DECODE_UNROLL.get():
-        layer_caches = []
-        for li in range(seg.n_layers):
-            p_layer = jax.tree.map(lambda a, li=li: a[li], p_seg)
-            cache_layer = jax.tree.map(lambda a, li=li: a[li], cache_seg)
-            x, nc, _ = blocks.block_decode(p_layer, x, cache_layer, pos,
-                                           seg.block, cfg.norm_eps,
-                                           paged=paged,
-                                           write_mask=write_mask)
-            layer_caches.append(nc)
-        new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *layer_caches)
-    else:
-        def body(h, xs):
-            p_layer, cache_layer = xs
-            y, new_cache, _ = blocks.block_decode(
-                p_layer, h, cache_layer, pos, seg.block, cfg.norm_eps,
-                paged=paged, write_mask=write_mask)
-            return y, new_cache
+    def body(h, p_layer, c, li):
+        y, nc, _ = blocks.block_decode(p_layer, h, c, pos, seg.block,
+                                       cfg.norm_eps, paged=paged,
+                                       write_mask=write_mask, layer=li)
+        return y, nc
 
-        x, new_cache = jax.lax.scan(body, x, (p_seg, cache_seg))
+    x, new_cache = _layer_loop(body, x, params["segments"][si]["blocks"],
+                               cache_seg, seg.n_layers, paged is not None,
+                               unroll=_DECODE_UNROLL.get())
     readout = None
     if seg.ramp:
         with (jax.named_scope(readout_scope) if readout_scope
@@ -297,16 +329,13 @@ def prefill_chunk_segment(params, cfg: ModelConfig, si: int, x: jax.Array,
     readout here — the engine reads the final head once, on the chunk
     that finishes the prompt."""
     seg = cfg.segments[si]
-    p_seg = params["segments"][si]["blocks"]
 
-    def body(h, xs):
-        p_layer, cache_layer = xs
-        y, new_cache = blocks.block_prefill_chunk(
-            p_layer, h, cache_layer, seg.block, cfg.norm_eps, table,
-            chunk)
-        return y, new_cache
+    def body(h, p_layer, c, li):
+        return blocks.block_prefill_chunk(p_layer, h, c, seg.block,
+                                          cfg.norm_eps, table, chunk, li)
 
-    x, new_cache = jax.lax.scan(body, x, (p_seg, cache_seg))
+    x, new_cache = _layer_loop(body, x, params["segments"][si]["blocks"],
+                               cache_seg, seg.n_layers, True)
     return constrain_batch(x), new_cache
 
 
@@ -352,18 +381,19 @@ def cache_specs(cfg: ModelConfig, batch: int, cache_len: int) -> list:
 def paged_cache_specs(cfg: ModelConfig, n_lanes: int, n_pages: int,
                       page_size: int) -> list:
     """Spec tree for the PAGED decode cache (DESIGN.md §8): attention
-    leaves swap the lane axis for the global page pool — ``(L, P,
-    page_size, ...)`` — while SSM state (no sequence axis to page) stays
-    lane-indexed ``(L, n_lanes, ...)``.  Leaf names match `cache_specs`
-    so the quant/dtype plumbing is shared."""
+    leaves swap the lane axis for the global page pool and put the
+    page-slot axis minor — ``(L, P, *row, page_size)``, the layout the
+    paged kernels read (`attention.pool_cache_defs`) — while SSM state
+    (no sequence axis to page) stays lane-indexed ``(L, n_lanes, ...)``.
+    Leaf names match `cache_specs` so the quant/dtype plumbing is
+    shared."""
     out = []
     for seg in cfg.segments:
-        pooled = blocks.cache_defs(seg.block, cfg.d_model, n_pages,
-                                   page_size)
         laned = blocks.cache_defs(seg.block, cfg.d_model, n_lanes, 1)
         entry = {}
-        if "attn" in pooled:
-            entry["attn"] = pooled["attn"]
+        if "attn" in laned:
+            entry["attn"] = attention.pool_cache_defs(
+                seg.block.attn, n_pages, page_size)
         if "ssm" in laned:
             entry["ssm"] = laned["ssm"]
         out.append(_stack_specs(entry, seg.n_layers))

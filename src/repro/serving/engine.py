@@ -87,9 +87,8 @@ def _mask_lane_writes(new_cache, old_cache, active: jax.Array,
     ``(L, B, ...)``, so broadcast the lane mask over axis 1.
 
     In paged mode the attention leaves are page-pool shaped (no lane
-    axis) and the decode path already redirected masked lanes' writes to
-    the garbage page — only the lane-indexed SSM state still needs the
-    where()."""
+    axis) and the decode path already wrote nothing for masked lanes —
+    only the lane-indexed SSM state still needs the where()."""
     def sel(n, o):
         return jnp.where(active.reshape((1, -1) + (1,) * (n.ndim - 2)),
                          n, o)
@@ -185,9 +184,9 @@ def make_token_step(params, cfg: ModelConfig, strategies, *,
         `models.attention.PagedKV` handle after ``sid`` — the per-lane
         page tables plus this token's (page, slot) write targets, both
         planned host-side by `serving.kvpool.KVPool.prepare_step`.
-        Attention writes of exited/unoccupied lanes are redirected to
-        the garbage page inside the decode (same visibility semantics
-        as the ring path's masked writes).
+        Exited/unoccupied lanes write nothing into the pool inside the
+        decode (same visibility semantics as the ring path's masked
+        writes), and the pool is written in place (DESIGN.md §8).
       paged_kernel: trace the paged decode against the Pallas
         paged-attention kernel instead of the jnp page-table gather.
         The `attention.paged_kernel` contextvar is read at TRACE time,

@@ -23,8 +23,8 @@ One package threads through every serving subsystem:
     trace reader names each idle gap by the innermost span covering
     it, so one would name them all), and no device scope
     (``jax.named_scope``) names a kernel (the trace reduction would
-    count the scope's operations as that kernel): the pool relayout is
-    ``kv_layout``, not ``paged_attention``.
+    count the scope's operations as that kernel): the pool's in-place
+    row writes are ``page_write``, not ``paged_attention``.
   * `registry` — `MetricsRegistry`: counters/gauges/histograms with
     labels, absorbing the per-subsystem stats dicts behind one
     ``snapshot()`` / Prometheus-text / JSON surface.

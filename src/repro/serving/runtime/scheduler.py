@@ -53,9 +53,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models import attention as A
 from repro.models import model as M
 from repro.models.attention import PagedKV, PrefillChunk
 from repro.serving.engine import make_token_step
+from repro.serving.kvpool.alloc import GARBAGE_PAGE
 from repro.serving.obs.trace import TRACER, StepRecord
 from repro.serving.runtime.request import Request, RequestQueue
 from repro.strategy.base import init_lane
@@ -269,8 +271,13 @@ class EngineStepper:
             self.prefill_chunk, prefill_budget)
         self.walk_io = bool(walk_io)
         self._n_up = self._up_bytes = 0     # host->device arrays made
+        # every program that writes the caches takes them donated off the
+        # CPU (make_token_step's rule), so the pool is updated in place
+        # and never held twice
+        donate = {"donate_argnums": (0,)} \
+            if jax.default_backend() != "cpu" else {}
         self._step = make_token_step(params, cfg, strategies, jit=jit,
-                                     donate=False, carry_state=True,
+                                     carry_state=True,
                                      paged=(kv == "paged"),
                                      paged_kernel=paged_kernel,
                                      prefill_slots=self.prefill_chunk or 0,
@@ -285,9 +292,9 @@ class EngineStepper:
                                max_lane_pages=max_lane_pages,
                                model_key=model_key)
             admit_fn = self._make_paged_admit()
-            self._prep = jax.jit(self._paged_prep) if jit \
+            self._prep = jax.jit(self._paged_prep, **donate) if jit \
                 else self._paged_prep
-            self._reset = jax.jit(self._reset_pages) if jit \
+            self._reset = jax.jit(self._reset_pages, **donate) if jit \
                 else self._reset_pages
         else:
             self.pool = None
@@ -306,9 +313,11 @@ class EngineStepper:
                         pos.at[lane].set(npos[0].astype(jnp.int32)))
 
         # weights enter the admit program as an argument, never as
-        # constants (see make_token_step)
+        # constants (see make_token_step); the caches follow them
         self._admit = functools.partial(
-            jax.jit(admit_fn) if jit else admit_fn, params)
+            jax.jit(admit_fn, donate_argnums=tuple(
+                i + 1 for i in donate.get("donate_argnums", ())))
+            if jit else admit_fn, params)
         self.alloc()
 
     # ---- paged device programs ----------------------------------------
@@ -317,13 +326,20 @@ class EngineStepper:
         cfg, prompt_len = self.cfg, self.prompt_len
 
         def admit_fn(params, caches, tok, pos, prompt, lane, dest_page,
-                     dest_slot, pos_vals, new_pages):
+                     pos_vals, new_pages):
             # prefill at cache_len == prompt_len: the ring layout is the
             # identity (slot t <- position t), so the per-token page
             # scatter below reads positions straight through
             logits, pc, _, npos = M.prefill(params, cfg,
                                             {"tokens": prompt}, prompt_len)
             t0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)[0]
+            # the prompt's rows (position t in slot t % page of its page)
+            # land in place; shared-prefix tokens (garbage destination)
+            # write nothing
+            ps = self.pool.page_size
+            targets = A.pool_targets(
+                dest_page[None], jnp.zeros((1,), jnp.int32),
+                dest_page[None] != GARBAGE_PAGE, -(-prompt_len // ps), ps)
             out = []
             for si in range(len(cfg.segments)):
                 seg_c = dict(caches[si])
@@ -332,15 +348,13 @@ class EngineStepper:
                     # gate stale bytes of freshly allocated pages
                     # (garbage-page padding makes this idempotent)
                     attn["pos"] = attn["pos"].at[:, new_pages].set(-1)
-                    for name, pool_leaf in attn.items():
-                        if name == "pos":
-                            attn["pos"] = attn["pos"].at[
-                                :, dest_page, dest_slot].set(pos_vals)
-                        else:
-                            attn[name] = pool_leaf.at[
-                                :, dest_page, dest_slot].set(
-                                    pc[si]["attn"][name][:, 0].astype(
-                                        pool_leaf.dtype))
+                    n_layers = attn["pos"].shape[0]
+                    rows = dict(pc[si]["attn"], pos=jnp.broadcast_to(
+                        pos_vals, (n_layers, 1, prompt_len)))
+                    for name in attn:
+                        for li in range(n_layers):
+                            attn[name] = A.pool_write(
+                                attn[name], li, targets, rows[name][li])
                     seg_c["attn"] = attn
                 if "ssm" in seg_c:
                     seg_c["ssm"] = jax.tree.map(
@@ -400,6 +414,7 @@ class EngineStepper:
                                         self.pool.page_size)
         else:
             specs = M.cache_specs(self.cfg, self.n_lanes, self.cache_len)
+        self.caches = None      # free the old caches before the new exist
         self.caches = [_materialize_cache(s) for s in specs]
         self.tok = jnp.zeros((self.n_lanes,), jnp.int32)
         self.pos = jnp.zeros((self.n_lanes,), jnp.int32)
@@ -489,8 +504,7 @@ class EngineStepper:
             self.caches, self.tok, self.pos = self._admit(
                 self.caches, self.tok, self.pos, prompt,
                 self._put(lane, jnp.int32), self._put(plan.dest_page),
-                self._put(plan.dest_slot), self._put(plan.pos_vals),
-                self._put(plan.new_pages))
+                self._put(plan.pos_vals), self._put(plan.new_pages))
         else:
             self.caches, self.tok, self.pos = self._admit(
                 self.caches, self.tok, self.pos, prompt,

@@ -16,16 +16,17 @@ KEY = jax.random.PRNGKey(0)
 
 def _chunk_setup(rng, *, b, hkv, hd, ps, maxp, starts, widths, c,
                  dtype=jnp.float32):
-    """Random pool + per-lane sequential history tables: lane ``i`` has
+    """Random pool in the stored layout (k/v (P, Hkv, hd, ps), pos
+    (P, 1, ps)) + per-lane sequential history tables: lane ``i`` has
     history positions [0, starts[i]) committed to its pages and a chunk
     of ``widths[i]`` in-flight queries at [starts[i], starts[i] +
     widths[i]) (width 0 = idle prefill slot: all rows padded)."""
     n_pages = 1 + sum(-(-s // ps) for s in starts)
-    k_pages = jnp.asarray(rng.normal(size=(n_pages, ps, hkv, hd)) * 0.5,
+    k_pages = jnp.asarray(rng.normal(size=(n_pages, hkv, hd, ps)) * 0.5,
                           dtype)
-    v_pages = jnp.asarray(rng.normal(size=(n_pages, ps, hkv, hd)) * 0.5,
+    v_pages = jnp.asarray(rng.normal(size=(n_pages, hkv, hd, ps)) * 0.5,
                           dtype)
-    pos_pages = np.full((n_pages, ps), -1, np.int32)
+    pos_pages = np.full((n_pages, 1, ps), -1, np.int32)
     table = np.zeros((b, maxp), np.int32)
     nxt = 1
     for lane, s in enumerate(starts):
@@ -33,12 +34,12 @@ def _chunk_setup(rng, *, b, hkv, hd, ps, maxp, starts, widths, c,
             table[lane, j] = nxt
             lo = j * ps
             w = min(ps, s - lo)
-            pos_pages[nxt, :w] = np.arange(lo, lo + w)
+            pos_pages[nxt, 0, :w] = np.arange(lo, lo + w)
             # mid-page boundary: fill the tail-page remainder with STALE
             # positions >= start — entries the chunk itself would have
             # scattered before attending; the kernel must mask them
             if w < ps:
-                pos_pages[nxt, w:] = np.arange(s, s + ps - w)
+                pos_pages[nxt, 0, w:] = np.arange(s, s + ps - w)
             nxt += 1
     q_pos = np.full((b, c), -1, np.int32)
     for lane, (s, w) in enumerate(zip(starts, widths)):
@@ -63,8 +64,7 @@ def _run_both(rng, *, b, h, hkv, hd, ps, maxp, starts, widths, c, window,
     n_hist = jnp.clip(-(-chunk_start // ps), 0, maxp)
     qr = q.reshape(b, c, hkv, g, hd).transpose(0, 2, 1, 3, 4)
     r = ref.paged_prefill_ref(
-        qr, q_pos, k_pages.transpose(0, 2, 1, 3),
-        v_pages.transpose(0, 2, 1, 3), pos_pages, table, chunk_start,
+        qr, q_pos, k_pages, v_pages, pos_pages, table, chunk_start,
         n_hist, ck.transpose(0, 2, 1, 3), cv.transpose(0, 2, 1, 3),
         q_pos, scale=scale, window=window)
     r = np.asarray(r, np.float32).transpose(0, 2, 1, 3, 4).reshape(
@@ -83,6 +83,10 @@ def _run_both(rng, *, b, h, hkv, hd, ps, maxp, starts, widths, c, window,
     (3, 4, 4, 64, 8, 3, (12, 0, 0), (4, 0, 6), 6, None),
     # sliding window crossing the history/chunk seam
     (2, 8, 2, 80, 8, 4, (20, 9), (6, 4), 6, 10),
+    # paper-ee: group 1 at hd 64, a chunk crossing a page
+    (2, 12, 12, 64, 16, 3, (21, 16), (8, 5), 8, None),
+    # granite: group 4 at hd 64
+    (2, 16, 4, 64, 16, 3, (32, 7), (6, 6), 6, None),
 ])
 def test_paged_prefill_matches_ref(b, h, hkv, hd, ps, maxp, starts,
                                    widths, c, window, dtype):

@@ -3,9 +3,11 @@
 The TPU compiler is installed even where no chip is attached, so the
 paged kernels are compiled here for a described v5e at paper-ee-100m
 widths: what Mosaic refuses (block shapes off the (8, 128) tiling, too
-much VMEM) fails here and not on the chip.  The last test lowers the
-full-width serve step on the CPU from shapes alone and checks that the
-weights enter it as arguments, not as constants baked into the program.
+much VMEM) fails here and not on the chip.  The full-width serve step
+is compiled there too, donated, to check that the KV pool stays in
+place (DESIGN.md §8); and lowered on the CPU from shapes alone, to
+check that the weights enter it as arguments, not as constants baked
+into the program.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
@@ -13,6 +15,7 @@ imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +32,7 @@ from repro.models.param import abstract
 from repro.serving.engine import make_token_step
 
 # paper-ee-100m attention: 12 query heads over 12 KV heads, head_dim 64
-# (ops pads it to 128 and the query group of 1 to 8); the serve shape of
+# (ops pads the query group of 1 to 8, and not hd); the serve shape of
 # chip_smoke.py: 16 lanes, 1024-token lane capacity, 128-token chunks
 LANES, HEADS, KV_HEADS, HEAD_DIM = 16, 12, 12, 64
 CACHE_LEN, CHUNK = 1024, 128
@@ -71,8 +74,9 @@ def _pool_shapes(one_chip, ps, pool_dtype):
     def s(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    kv = s((n_pages, ps, KV_HEADS, HEAD_DIM), pool_dtype)
-    return s, kv, s((n_pages, ps), jnp.int32), s((LANES, maxp), jnp.int32)
+    kv = s((n_pages, KV_HEADS, HEAD_DIM, ps), pool_dtype)
+    return (s, kv, s((n_pages, 1, ps), jnp.int32),
+            s((LANES, maxp), jnp.int32))
 
 
 @pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8],
@@ -99,6 +103,135 @@ def test_paged_kernel_compiles_for_v5e(one_chip, no_persistent_cache,
                 s((LANES, CHUNK, KV_HEADS, HEAD_DIM), bf))
     compiled = jax.jit(f).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _largest(shape_text: str) -> tuple:
+    """Dims of the largest array in an HLO result shape (a tuple for
+    copy-start)."""
+    shapes = [tuple(int(d) for d in dims.split(",") if d)
+              for dims in re.findall(r"\w+\[([\d,]*)\]", shape_text)]
+    return max(shapes, key=lambda sh: int(np.prod(sh, dtype=np.int64)),
+               default=())
+
+
+def test_donated_full_width_step_keeps_the_pool_in_place(
+        one_chip, no_persistent_cache):
+    """The full-width paged + chunked step at paper-ee widths (32 lanes,
+    257 pages of 128), donated and compiled for a described v5e: every
+    pool leaf is aliased to its output, no copy, transpose, pad or
+    dynamic-slice the size of one layer's K pool is left anywhere in
+    the program, the arguments do not grow, and its temporaries stay
+    below the pool itself."""
+    cfg = get_config("paper-ee-100m", smoke=False)
+    lanes, n_pages, ps = 32, 257, 128
+    maxp = CACHE_LEN // ps
+    bf = jnp.bfloat16
+
+    def s(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(tuple(shape), dt, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype),
+                          abstract(M.model_defs(cfg), bf))
+    casc = strategy.Cascade.uniform(cfg.n_ramps + 1, lam=0.5)
+    bank = (strategy.make("always_last", casc),)
+    caches = jax.tree.map(
+        lambda spec: s(*spec),
+        M.paged_cache_specs(cfg, lanes, n_pages, ps),
+        is_leaf=lambda x: isinstance(x, tuple) and len(x) == 2
+        and isinstance(x[0], tuple))
+    states = jax.tree.map(lambda a: s(a.shape, a.dtype), jax.eval_shape(
+        lambda: tuple(st.init(lanes) for st in bank)))
+    rows = s((lanes, CHUNK))
+    chunk = PrefillChunk(tok=rows, pos=rows, dest_page=rows, dest_slot=rows,
+                         start=s((lanes,)), last_idx=s((lanes,)),
+                         emit=s((lanes,), bool), active=s((lanes,), bool))
+    kv = PagedKV(page_table=s((lanes, maxp)), write_page=s((lanes,)),
+                 write_slot=s((lanes,)))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "on_cpu", lambda: False)     # Mosaic, not interpret
+        step = make_token_step(params, cfg, bank, donate=True,
+                               carry_state=True, paged=True,
+                               paged_kernel=True, prefill_slots=CHUNK)
+        compiled = step.func.lower(
+            params, s((lanes,)), caches, s((lanes,)), s((lanes,), bool),
+            s((lanes,)), kv, states, chunk).compile()
+    text = compiled.as_text()
+    ma = compiled.memory_analysis()
+    leaves = jax.tree.leaves(caches)
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
+    assert pool_bytes == 1_214_257_152
+    aliases = re.search(r"input_output_alias=\{(.*?)\}\s*,?\s*entry_",
+                        text)
+    assert aliases, "no input_output_alias: the pool is not donated"
+    assert len(re.findall(r"may-alias|must-alias",
+                          aliases.group(1))) == len(leaves)
+    assert ma.alias_size_in_bytes == pool_bytes
+    # the stored layout pads nothing: the arguments (weights, pool,
+    # inputs) take no more than with the hd-minor pool before it
+    assert ma.argument_size_in_bytes <= 1_461_456_896
+    attn = cfg.segments[0].block.attn
+    layer_pool = n_pages * ps * attn.n_kv_heads * attn.head_dim
+    # a weight as large (the tied embedding, which the readouts read
+    # whole) may be staged into faster memory; no pool array may move
+    weights = {tuple(a.shape) for a in jax.tree.leaves(params)}
+    moved = []
+    for line in text.splitlines():
+        m = re.search(r"= (.*?) ([a-z][a-z\-]*)\(", line)
+        if not m or m.group(2) not in ("copy", "copy-start", "transpose",
+                                       "pad", "dynamic-slice"):
+            continue
+        dims = _largest(m.group(1))
+        if np.prod(dims, dtype=np.int64) >= layer_pool \
+                and dims not in weights:
+            moved.append(line.strip()[:160])
+    assert not moved, moved
+    assert text.count("tpu_custom_call") >= 2
+    assert ma.temp_size_in_bytes < pool_bytes, ma.temp_size_in_bytes
+
+
+def test_stepper_programs_donate_every_pool_leaf(one_chip):
+    """Off the CPU, `EngineStepper` builds every program that writes the
+    pool — the fused step, the page ops (`_prep`), the admission reset
+    (`_reset`) and the admit program — with the caches donated: lowered
+    for the described v5e, each marks every pool leaf as donated."""
+    from repro.serving import runtime as rt
+
+    cfg = get_config("paper-ee-100m", smoke=True)
+    params = abstract(M.model_defs(cfg), jnp.float32)
+    casc = strategy.Cascade.uniform(cfg.n_ramps + 1, lam=0.5)
+    bank = (strategy.make("always_last", casc),)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        stepper = rt.EngineStepper(params, cfg, bank, n_lanes=2,
+                                   cache_len=32, prompt_len=8, kv="paged",
+                                   page_size=8, paged_kernel=True,
+                                   prefill_chunk=4)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    n, lp = stepper.n_lanes, stepper.pool.lane_pages
+    i32 = np.zeros(n, np.int32)
+    kv = PagedKV(np.zeros((n, lp), np.int32), i32, i32)
+    chunk, _, _ = stepper._build_chunk({})
+    prompt = np.zeros((1, 8), np.int32)
+    caches = on_chip(stepper.caches)
+    lowered = {
+        "step": stepper._step.func.lower(*on_chip(
+            (params, stepper.tok, stepper.caches, stepper.pos,
+             np.ones(n, bool), i32, kv, stepper.states, chunk))),
+        "prep": stepper._prep.lower(caches, *on_chip((i32, i32, i32))),
+        "reset": stepper._reset.lower(caches, on_chip(i32)),
+        "admit": stepper._admit.func.lower(*on_chip(
+            (params, stepper.caches, stepper.tok, stepper.pos, prompt,
+             np.int32(0), prompt[0], prompt[0], np.zeros(lp, np.int32)))),
+    }
+    where = {"step": 2, "prep": 0, "reset": 0, "admit": 1}
+    for name, low in lowered.items():
+        info = jax.tree.leaves(low.args_info[0][where[name]])
+        assert len(info) == len(jax.tree.leaves(stepper.caches)), name
+        assert all(a.donated for a in info), name
 
 
 def _constant_sizes(module) -> list[int]:

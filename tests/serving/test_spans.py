@@ -271,11 +271,11 @@ def test_step_program_scopes_are_named_and_name_no_kernel(engine):
         params, *args, kv, stepper.states, chunk).as_text(debug_info=True)
     stacks = [n.split("/") for n in re.findall(r'loc\("([^"]+)"', text)]
     scopes = ("segment0", "segment1", "readout0", "chunk_sweep",
-              "kv_layout")
+              "page_write")
     for scope in scopes:
         assert any(scope in parts for parts in stacks), scope
     for parts in stacks:
         if any(p.startswith(("segment", "readout", "chunk_sweep",
-                             "kv_layout")) for p in parts):
+                             "page_write")) for p in parts):
             assert not any("paged_attention" in p or "paged_prefill" in p
                            for p in parts), "/".join(parts)
