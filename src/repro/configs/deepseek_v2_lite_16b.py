@@ -22,20 +22,32 @@ def _attn(nope=128, rope=64, v=128, lora=512, heads=16):
                       qk_rope_head_dim=rope, v_head_dim=v))
 
 
-def full_config() -> ModelConfig:
-    attn = _attn()
-    dense0 = BlockConfig(mixer="attn", attn=attn, mlp="dense", d_ff=10944)
-    moe = MoEConfig(num_experts=64, top_k=6, d_ff_expert=1408,
-                    num_shared=2, d_ff_shared=2816)
+def deepseek_v2_lite(name: str, *, d_model: int, vocab: int,
+                     attn: AttnConfig, dense_ff: int, moe: MoEConfig,
+                     moe_sizes: list[int], ramp_after_dense: bool,
+                     norm_eps: float = 1e-5) -> ModelConfig:
+    """One dense MLA layer, then MoE MLA layers in segments of
+    ``moe_sizes``; a ramp after every MoE segment but the last (the
+    head), and after the dense layer if ``ramp_after_dense``."""
+    dense0 = BlockConfig(mixer="attn", attn=attn, mlp="dense", d_ff=dense_ff)
     moe_block = BlockConfig(mixer="attn", attn=attn, mlp="moe", moe=moe)
-    # 1 dense layer + 26 MoE layers; ramps every ~5 MoE layers -> 6 nodes.
-    moe_sizes = [5, 5, 5, 5, 6]
-    segments = [Segment(block=dense0, n_layers=1, ramp=False)]
+    segments = [Segment(block=dense0, n_layers=1, ramp=ramp_after_dense)]
     segments += [Segment(block=moe_block, n_layers=s,
                          ramp=(i < len(moe_sizes) - 1))
                  for i, s in enumerate(moe_sizes)]
-    return ModelConfig(name=ARCH_ID, d_model=2048, vocab=102_400,
-                       segments=tuple(segments), tie_embeddings=False)
+    return ModelConfig(name=name, d_model=d_model, vocab=vocab,
+                       segments=tuple(segments), tie_embeddings=False,
+                       norm_eps=norm_eps)
+
+
+def full_config() -> ModelConfig:
+    # 1 dense layer + 26 MoE layers; ramps every ~5 MoE layers -> 6 nodes.
+    moe = MoEConfig(num_experts=64, top_k=6, d_ff_expert=1408,
+                    num_shared=2, d_ff_shared=2816)
+    return deepseek_v2_lite(ARCH_ID, d_model=2048, vocab=102_400,
+                            attn=_attn(), dense_ff=10944, moe=moe,
+                            moe_sizes=[5, 5, 5, 5, 6],
+                            ramp_after_dense=False)
 
 
 def smoke_config() -> ModelConfig:

@@ -400,7 +400,8 @@ def attn_forward(p: dict, x: jax.Array, positions: jax.Array,
     if cfg.qk_norm:
         q = rms_norm({"scale": p["q_norm"]}, q, eps)
         k = rms_norm({"scale": p["k_norm"]}, k, eps)
-    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
+    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta,
+                            cfg.yarn)
     q = rope(q, cos, sin)
     k = rope(k, cos, sin)
     scale = cfg.softmax_scale or 1.0 / math.sqrt(cfg.head_dim)
@@ -444,7 +445,8 @@ def _gqa_qkv_decode(p: dict, x: jax.Array, pos: jax.Array, cfg: AttnConfig,
     if cfg.qk_norm:
         q = rms_norm({"scale": p["q_norm"]}, q, eps)
         k = rms_norm({"scale": p["k_norm"]}, k, eps)
-    cos, sin = rope_cos_sin(pos[:, None], cfg.head_dim, cfg.rope_theta)
+    cos, sin = rope_cos_sin(pos[:, None], cfg.head_dim, cfg.rope_theta,
+                            cfg.yarn)
     return rope(q, cos, sin), rope(k, cos, sin), v
 
 
@@ -528,13 +530,10 @@ def attn_prefill_chunk(p: dict, x: jax.Array, pool: dict,
 
     x (B, C, D); pool: a layer-stacked pool (L, P, ...) whose ``layer``
     this is; table (B, maxp) i32 page table; returns (y (B, C, D),
-    new_pool).  MLA segments are not yet chunkable — serve them through
-    the stop-the-world admission path.
+    new_pool).  MLA segments take `_mla_prefill_chunk`.
     """
     if cfg.mla is not None:
-        raise NotImplementedError(
-            "chunked prefill supports GQA attention only; MLA segments "
-            "must admit through the whole-prompt prefill path")
+        return _mla_prefill_chunk(p, x, pool, cfg, eps, table, chunk, layer)
     b, c, _ = x.shape
     ps = pool["k"].shape[-1]
     rpos = jnp.maximum(chunk.pos, 0)          # rope of pad rows: masked
@@ -544,7 +543,7 @@ def attn_prefill_chunk(p: dict, x: jax.Array, pool: dict,
     if cfg.qk_norm:
         q = rms_norm({"scale": p["q_norm"]}, q, eps)
         k = rms_norm({"scale": p["k_norm"]}, k, eps)
-    cos, sin = rope_cos_sin(rpos, cfg.head_dim, cfg.rope_theta)
+    cos, sin = rope_cos_sin(rpos, cfg.head_dim, cfg.rope_theta, cfg.yarn)
     q = rope(q, cos, sin)
     k = rope(k, cos, sin)
 
@@ -658,82 +657,164 @@ def _mla_q(p, x, cfg: AttnConfig, eps):
     return q[..., :m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
 
 
+def _mla_latent(p, x, positions, cfg: AttnConfig, eps):
+    """What the latent cache stores for x (..., S, D) at positions
+    (..., S): c_kv (..., S, lora) after ``kv_norm`` and the shared
+    k_rope (..., S, rope) after rotary; and the rotary's cos/sin."""
+    m = cfg.mla
+    dkv = x @ p["w_dkv"]
+    c_kv = rms_norm({"scale": p["kv_norm"]}, dkv[..., :m.kv_lora_rank], eps)
+    cos, sin = rope_cos_sin(positions, m.qk_rope_head_dim, cfg.rope_theta,
+                            cfg.yarn)
+    k_rope = rope(dkv[..., None, m.kv_lora_rank:], cos, sin)[..., 0, :]
+    return c_kv, k_rope, cos, sin
+
+
+def _mla_scale(cfg: AttnConfig) -> float:
+    m = cfg.mla
+    return cfg.softmax_scale or 1.0 / math.sqrt(
+        m.qk_nope_head_dim + m.qk_rope_head_dim)
+
+
 def _mla_forward(p, x, positions, cfg: AttnConfig, eps):
     m = cfg.mla
     b, s, _ = x.shape
     h = cfg.n_heads
     q_nope, q_rope = _mla_q(p, x, cfg, eps)
-    dkv = x @ p["w_dkv"]
-    c_kv = rms_norm({"scale": p["kv_norm"]}, dkv[..., :m.kv_lora_rank], eps)
-    k_rope = dkv[..., m.kv_lora_rank:]                       # (B,S,rope)
-    cos, sin = rope_cos_sin(positions, m.qk_rope_head_dim, cfg.rope_theta)
+    c_kv, k_rope, cos, sin = _mla_latent(p, x, positions, cfg, eps)
     q_rope = rope(q_rope, cos, sin)
-    k_rope = rope(k_rope[..., None, :], cos, sin)            # (B,S,1,rope)
 
     k_nope = (c_kv @ p["w_uk"]).reshape(b, s, h, m.qk_nope_head_dim)
     v = (c_kv @ p["w_uv"]).reshape(b, s, h, m.v_head_dim)
     k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope, (b, s, h, m.qk_rope_head_dim))],
-        axis=-1)
+        [k_nope, jnp.broadcast_to(k_rope[:, :, None],
+                                  (b, s, h, m.qk_rope_head_dim))], axis=-1)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    scale = cfg.softmax_scale or 1.0 / math.sqrt(
-        m.qk_nope_head_dim + m.qk_rope_head_dim)
+    scale = _mla_scale(cfg)
     if s >= _CHUNK_THRESHOLD and s % _Q_CHUNK == 0:
         out = _sdpa_chunked(q, k, v, positions, positions, cfg.window, scale)
     else:
         mask = causal_mask(positions, positions, cfg.window)
         out = _sdpa(q, k, v, mask, scale)
     y = out.reshape(b, s, -1) @ p["wo"]
-    return y, {"c_kv": c_kv, "k_rope": k_rope[..., 0, :]}
+    return y, {"c_kv": c_kv, "k_rope": k_rope}
 
 
+def _latent_rows(cache: dict, c_kv, k_rope) -> dict:
+    """The latent rows a write stores (..., S, *): in the cache's dtype,
+    or int8 with per-position scales under models.quant."""
+    if "c_kv_s" in cache:
+        from repro.models.quant import quantize_rows
+        cq, cs = quantize_rows(c_kv)
+        rq, rs = quantize_rows(k_rope)
+        return {"c_kv": cq, "c_kv_s": cs, "k_rope": rq, "k_rope_s": rs}
+    return {"c_kv": c_kv.astype(cache["c_kv"].dtype),
+            "k_rope": k_rope.astype(cache["k_rope"].dtype)}
+
+
+def _latent_history(pool: dict, layer, table):
+    """The jnp gather path's view of lanes' pages of ``layer``: c_kv
+    (B, T, lora), k_rope (B, T, rope) and positions (B, T), T = maxp *
+    ps; int8 pools gather the lanes' pages FIRST, then dequantize only
+    those (never the whole pool)."""
+    ckv = _pool_gather(pool["c_kv"], layer, table)
+    krope = _pool_gather(pool["k_rope"], layer, table)
+    if "c_kv_s" in pool:
+        from repro.models.quant import dequantize_rows
+        ckv = dequantize_rows(
+            ckv, _pool_gather(pool["c_kv_s"], layer, table)[..., 0])
+        krope = dequantize_rows(
+            krope, _pool_gather(pool["k_rope_s"], layer, table)[..., 0])
+    return ckv, krope, _pool_gather(pool["pos"], layer, table)[..., 0]
+
+
+def _mla_absorbed(p, q_nope, q_rope, ckv, krope, mask, cfg: AttnConfig,
+                  dtype):
+    """Attention in the compressed kv_lora space, W_uk absorbed into the
+    query and W_uv applied after (DESIGN.md §4, §9).  q_nope (..., Q,
+    H, nope), q_rope (..., Q, H, rope) with rotary applied; ckv (..., T,
+    lora), krope (..., T, rope); mask (..., Q, T).  Returns (..., Q, H,
+    v_head_dim)."""
+    m, h = cfg.mla, cfg.n_heads
+    w_uk = p["w_uk"].reshape(m.kv_lora_rank, h, m.qk_nope_head_dim)
+    q_c = jnp.einsum("...qhn,rhn->...qhr", q_nope, w_uk)
+    scores = jnp.einsum("...qhr,...tr->...hqt", q_c, ckv.astype(q_c.dtype))
+    scores += jnp.einsum("...qhe,...te->...hqt", q_rope,
+                         krope.astype(q_rope.dtype))
+    logits = jnp.where(mask[..., None, :, :],
+                       scores.astype(jnp.float32) * _mla_scale(cfg), -1e30)
+    w = jax.nn.softmax(logits, axis=-1).astype(dtype)
+    ctx = jnp.einsum("...hqt,...tr->...qhr", w, ckv.astype(w.dtype))
+    w_uv = p["w_uv"].reshape(m.kv_lora_rank, h, m.v_head_dim)
+    return jnp.einsum("...qhr,rhv->...qhv", ctx, w_uv)
+
+
+@jax.named_scope("mla_chunk")
+def _mla_prefill_chunk(p, x, pool, cfg: AttnConfig, eps, table,
+                       chunk: PrefillChunk, layer):
+    """One prefill chunk of MLA against ``layer`` of the latent pool
+    (DESIGN.md §9): write the chunk's latent rows (c_kv after kv_norm,
+    k_rope after rotary) into their (page, slot) targets, then attend
+    causally over the lane's pages, its own rows included, with
+    positions from the pool's ``pos`` — absorbed, as decode attends.
+    Pad rows, prefix-cache hits and inactive lanes write nothing; each
+    lane attends on its own (``lax.map``) and a lane that prefills
+    nothing this step is skipped, so the step reads the latent pages of
+    the prefilling lanes only."""
+    b, c, _ = x.shape
+    ps = pool["c_kv"].shape[-1]
+    q_nope, q_rope = _mla_q(p, x, cfg, eps)
+    c_kv, k_rope, cos, sin = _mla_latent(p, x, jnp.maximum(chunk.pos, 0),
+                                         cfg, eps)
+    q_rope = rope(q_rope, cos, sin)
+    live = chunk.active[:, None] & (chunk.pos >= 0) \
+        & (chunk.dest_page != _GARBAGE_PAGE)
+    n_win = (c + ps - 2) // ps + 1
+    rows = dict(_latent_rows(pool, c_kv, k_rope),
+                pos=chunk.pos.astype(jnp.int32))
+    new_pool = _pool_commit(
+        pool, layer,
+        pool_targets(chunk.dest_page, chunk.dest_slot[:, 0], live, n_win,
+                     ps), rows)
+
+    def attend(args):
+        qn, qr, tab, qpos, _ = args
+        ckv, krope, kpos = _latent_history(new_pool, layer, tab[None])
+        mask = causal_mask(qpos, kpos[0], cfg.window) & (kpos >= 0)
+        return _mla_absorbed(p, qn, qr, ckv[0], krope[0], mask, cfg,
+                             x.dtype)
+
+    def skip(args):
+        return jnp.zeros((c, cfg.n_heads, cfg.mla.v_head_dim), x.dtype)
+
+    out = jax.lax.map(lambda a: jax.lax.cond(a[-1], attend, skip, a),
+                      (q_nope, q_rope, table, chunk.pos, chunk.active))
+    y = out.reshape(b, c, -1) @ p["wo"]
+    return y, new_pool
+
+
+@jax.named_scope("mla_decode")
 def _mla_decode(p, x, cache, pos, cfg: AttnConfig, eps,
                 paged: PagedKV | None = None, write_mask=None, layer=0):
     """Absorbed-matmul MLA decode: attention runs in the compressed
     kv_lora space — the cache stays (B, C, lora + rope) (ring) or
     (P, lora + rope, page) per layer (paged), which is the whole point
     of MLA (DESIGN.md §4)."""
-    m = cfg.mla
     b = x.shape[0]
-    h = cfg.n_heads
     q_nope, q_rope = _mla_q(p, x, cfg, eps)                  # (B,1,H,*)
-    dkv = x @ p["w_dkv"]
-    c_new = rms_norm({"scale": p["kv_norm"]}, dkv[..., :m.kv_lora_rank], eps)
-    k_rope_new = dkv[..., m.kv_lora_rank:]
-    cos, sin = rope_cos_sin(pos[:, None], m.qk_rope_head_dim, cfg.rope_theta)
+    c_new, k_rope_new, cos, sin = _mla_latent(p, x, pos[:, None], cfg, eps)
     q_rope = rope(q_rope, cos, sin)
-    k_rope_new = rope(k_rope_new[..., None, :], cos, sin)[..., 0, :]
-
-    int8 = "c_kv_s" in cache
-    if int8:  # int8 latent cache (models.quant)
-        from repro.models.quant import dequantize_rows, quantize_rows
-        cq, cs = quantize_rows(c_new[:, 0])
-        rq, rs = quantize_rows(k_rope_new[:, 0])
-        rows = {"c_kv": cq, "c_kv_s": cs, "k_rope": rq, "k_rope_s": rs}
-    else:
-        rows = {"c_kv": c_new[:, 0].astype(cache["c_kv"].dtype),
-                "k_rope": k_rope_new[:, 0].astype(cache["k_rope"].dtype)}
+    rows = _latent_rows(cache, c_new[:, 0], k_rope_new[:, 0])
     if paged is not None:
         # write the row into ``layer`` of the pool, then gather the
-        # lanes' pages back to the per-lane (B, C, ...) layout the
-        # absorbed-matmul score path below consumes unchanged; int8
-        # pools gather the lane's pages FIRST, then dequantize only
-        # those (never the whole pool)
+        # lanes' pages back to the per-lane (B, T, ...) layout
         rows["pos"] = pos.astype(jnp.int32)
         new_cache = _pool_commit(
             cache, layer,
             _decode_targets(paged, write_mask, cache["c_kv"].shape[-1]),
             {n: r[:, None] for n, r in rows.items()})
-        table = paged.page_table
-        ckv = _pool_gather(new_cache["c_kv"], layer, table)
-        krope = _pool_gather(new_cache["k_rope"], layer, table)
-        if int8:
-            ckv = dequantize_rows(
-                ckv, _pool_gather(new_cache["c_kv_s"], layer, table)[..., 0])
-            krope = dequantize_rows(
-                krope,
-                _pool_gather(new_cache["k_rope_s"], layer, table)[..., 0])
-        new_pos = _pool_gather(new_cache["pos"], layer, table)[..., 0]
+        ckv, krope, new_pos = _latent_history(new_cache, layer,
+                                              paged.page_table)
     else:
         c = cache["c_kv"].shape[1]
         bidx, slot = jnp.arange(b), (pos % c).astype(jnp.int32)
@@ -742,25 +823,13 @@ def _mla_decode(p, x, cache, pos, cfg: AttnConfig, eps,
         new_pos = cache["pos"].at[bidx, slot].set(pos.astype(jnp.int32))
         new_cache["pos"] = new_pos
         ckv, krope = new_cache["c_kv"], new_cache["k_rope"]
-        if int8:
+        if "c_kv_s" in cache:
+            from repro.models.quant import dequantize_rows
             ckv = dequantize_rows(ckv, new_cache["c_kv_s"])
             krope = dequantize_rows(krope, new_cache["k_rope_s"])
 
-    # Absorb W_uk into q: q_c[b,h,r] = sum_n q_nope[b,h,n] W_uk[r, h, n]
-    w_uk = p["w_uk"].reshape(m.kv_lora_rank, h, m.qk_nope_head_dim)
-    q_c = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_uk)
-    scores = jnp.einsum("bhr,btr->bht", q_c, ckv.astype(q_c.dtype))
-    scores += jnp.einsum("bhe,bte->bht", q_rope[:, 0],
-                         krope.astype(q_rope.dtype))
-    scale = cfg.softmax_scale or 1.0 / math.sqrt(
-        m.qk_nope_head_dim + m.qk_rope_head_dim)
-    mask = causal_mask(pos[:, None], new_pos, cfg.window)[:, 0]  # (B,C)
-    mask &= new_pos >= 0
-    logits = jnp.where(mask[:, None, :], scores.astype(jnp.float32) * scale,
-                       -1e30)
-    w = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-    ctx_c = jnp.einsum("bht,btr->bhr", w, ckv.astype(w.dtype))  # (B,H,lora)
-    w_uv = p["w_uv"].reshape(m.kv_lora_rank, h, m.v_head_dim)
-    out = jnp.einsum("bhr,rhv->bhv", ctx_c, w_uv)
-    y = out.reshape(b, 1, h * m.v_head_dim) @ p["wo"]
+    mask = causal_mask(pos[:, None], new_pos, cfg.window)     # (B,1,T)
+    mask &= new_pos[:, None, :] >= 0
+    out = _mla_absorbed(p, q_nope, q_rope, ckv, krope, mask, cfg, x.dtype)
+    y = out.reshape(b, 1, -1) @ p["wo"]
     return y, new_cache

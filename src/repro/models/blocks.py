@@ -65,29 +65,48 @@ def _mixer_full(p, xn, positions, cfg: BlockConfig, eps, use_flash,
     return y, {"attn_kv": kv, "ssm": st}
 
 
+def _mlp(p: dict, x: jax.Array, cfg: BlockConfig, eps: float, live=None):
+    """The block's residual MLP.  Returns (x, aux).  For MoE blocks,
+    ``live`` (rows of x, bool) selects the serving expert layer
+    (`moe.moe_serve`, dropless: only live rows route, and aux holds its
+    counters); without it they take the training dispatch (aux holds
+    its losses)."""
+    if cfg.mlp == "dense":
+        return x + mlp_lib.mlp_forward(p["mlp"], rms_norm(p["norm2"], x, eps),
+                                       cfg.act), {}
+    if cfg.mlp == "moe":
+        xn = rms_norm(p["norm2"], x, eps)
+        if live is None:
+            y, aux = moe_lib.moe_forward(p["moe"], xn, cfg.moe, cfg.act)
+        else:
+            y, aux = moe_lib.moe_serve(p["moe"], xn, cfg.moe, cfg.act, live)
+        return x + y, aux
+    return x, {}
+
+
 def block_forward(p: dict, x: jax.Array, positions: jax.Array,
                   cfg: BlockConfig, eps: float = 1e-5,
-                  use_flash: bool = False, use_ssd_kernel: bool = False):
-    """Train/prefill pass.  Returns (y, cache_entry, aux)."""
-    aux: dict = {}
+                  use_flash: bool = False, use_ssd_kernel: bool = False,
+                  serve: bool = False):
+    """Train/prefill pass.  Returns (y, cache_entry, aux).  ``serve``
+    (the serving prefill) routes MoE rows through the dropless serving
+    layer, so a prompt's experts are those its decode would use."""
     xn = rms_norm(p["norm1"], x, eps)
     mix, cache = _mixer_full(p, xn, positions, cfg, eps, use_flash,
                              use_ssd_kernel)
-    x = x + mix
-    if cfg.mlp == "dense":
-        x = x + mlp_lib.mlp_forward(p["mlp"], rms_norm(p["norm2"], x, eps),
-                                    cfg.act)
-    elif cfg.mlp == "moe":
-        y, aux = moe_lib.moe_forward(p["moe"], rms_norm(p["norm2"], x, eps),
-                                     cfg.moe, cfg.act)
-        x = x + y
+    live = jnp.ones(x.shape[:-1], bool) if serve and cfg.mlp == "moe" \
+        else None
+    x, aux = _mlp(p, x + mix, cfg, eps, live)
     return x, cache, aux
 
 
 def block_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
                  cfg: BlockConfig, eps: float = 1e-5,
                  paged=None, write_mask=None, layer=0):
-    """One-token step.  x (B,1,D); returns (y, new_cache, aux).
+    """One-token step.  x (B,1,D); returns (y, new_cache, aux): MoE
+    blocks route through the dropless serving layer, the lanes of
+    ``write_mask`` only (every lane without it), and aux holds its
+    counters.
 
     ``paged``/``write_mask`` switch the attention cache to the paged KV
     pool (attention.PagedKV): ``cache["attn"]`` is then the segment's
@@ -95,7 +114,6 @@ def block_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
     stays lane-indexed either way — its per-lane masking is the
     engine's job.
     """
-    aux: dict = {}
     xn = rms_norm(p["norm1"], x, eps)
     new_cache: dict = {}
     if cfg.mixer == "attn":
@@ -113,26 +131,25 @@ def block_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
             p["ssm"], xn, cache["ssm"], cfg.ssm, eps)
         mix = 0.5 * (rms_norm(p["attn_out_norm"], ya, eps)
                      + rms_norm(p["ssm_out_norm"], ys, eps))
-    x = x + mix
-    if cfg.mlp == "dense":
-        x = x + mlp_lib.mlp_forward(p["mlp"], rms_norm(p["norm2"], x, eps),
-                                    cfg.act)
-    elif cfg.mlp == "moe":
-        y, aux = moe_lib.moe_forward(p["moe"], rms_norm(p["norm2"], x, eps),
-                                     cfg.moe, cfg.act)
-        x = x + y
+    live = None
+    if cfg.mlp == "moe":
+        live = jnp.ones(x.shape[:-1], bool) if write_mask is None \
+            else jnp.broadcast_to(write_mask[:, None], x.shape[:-1])
+    x, aux = _mlp(p, x + mix, cfg, eps, live)
     return x, new_cache, aux
 
 
 def block_prefill_chunk(p: dict, x: jax.Array, cache: dict,
                         cfg: BlockConfig, eps: float, table: jax.Array,
-                        chunk, layer=0) -> tuple[jax.Array, dict]:
+                        chunk, layer=0):
     """One prefill CHUNK through a block against ``layer`` of the
     segment's layer-stacked paged pool (DESIGN.md §9).  x (B, C, D);
-    returns (y, new_cache).  Only
-    attention mixers are chunkable — SSM state is inherently sequential
-    over the whole prompt, so ssm/hybrid models admit through the
-    stop-the-world prefill path (gated at EngineStepper construction).
+    returns (y, new_cache, aux): MoE blocks route the chunk's live rows
+    (active lanes, real positions) only, and aux holds their counters.
+    Only attention mixers (GQA and MLA) are chunkable — SSM state is
+    inherently sequential over the whole prompt, so ssm/hybrid models
+    admit through the stop-the-world prefill path (gated at
+    EngineStepper construction).
     """
     if cfg.mixer != "attn":
         raise NotImplementedError(
@@ -141,15 +158,10 @@ def block_prefill_chunk(p: dict, x: jax.Array, cache: dict,
     xn = rms_norm(p["norm1"], x, eps)
     mix, new_attn = attention.attn_prefill_chunk(
         p["attn"], xn, cache["attn"], cfg.attn, eps, table, chunk, layer)
-    x = x + mix
-    if cfg.mlp == "dense":
-        x = x + mlp_lib.mlp_forward(p["mlp"], rms_norm(p["norm2"], x, eps),
-                                    cfg.act)
-    elif cfg.mlp == "moe":
-        y, _ = moe_lib.moe_forward(p["moe"], rms_norm(p["norm2"], x, eps),
-                                   cfg.moe, cfg.act)
-        x = x + y
-    return x, {"attn": new_attn}
+    live = chunk.active[:, None] & (chunk.pos >= 0) \
+        if cfg.mlp == "moe" else None
+    x, aux = _mlp(p, x + mix, cfg, eps, live)
+    return x, {"attn": new_attn}, aux
 
 
 def build_ring_cache(cache_entry: dict, positions: jax.Array,

@@ -13,8 +13,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Literal
 
-__all__ = ["MLAConfig", "AttnConfig", "SSMConfig", "MoEConfig",
-           "BlockConfig", "Segment", "ModelConfig"]
+__all__ = ["MLAConfig", "YarnConfig", "AttnConfig", "SSMConfig",
+           "MoEConfig", "BlockConfig", "Segment", "ModelConfig"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -28,6 +28,17 @@ class MLAConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (DeepSeek-V2's ``rope_scaling``, type yarn)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class AttnConfig:
     n_heads: int
     n_kv_heads: int
@@ -37,6 +48,7 @@ class AttnConfig:
     window: int | None = None        # sliding-window size (None = full)
     mla: MLAConfig | None = None
     softmax_scale: float | None = None
+    yarn: YarnConfig | None = None   # rope frequencies (None = plain)
 
     @property
     def q_dim(self) -> int:
@@ -65,6 +77,13 @@ class SSMConfig:
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """Routed experts (``num_experts`` wide router, ``top_k`` per token)
+    plus optional shared experts.  ``held_experts`` gives this chip's
+    share under expert parallelism: the router keeps all ``num_experts``
+    outputs, the layer holds and computes experts
+    ``0 .. held_experts - 1`` only (None = all; another rank's share is
+    the same layer with the router's columns rotated).  Routed gates are
+    not scaled (DeepSeek-V2's ``routed_scaling_factor`` is 1)."""
     num_experts: int
     top_k: int
     d_ff_expert: int
@@ -73,6 +92,13 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
     router_z_weight: float = 1e-3
+    norm_topk_prob: bool = True      # renormalise the top-k gates
+    held_experts: int | None = None
+
+    @property
+    def n_held(self) -> int:
+        return self.num_experts if self.held_experts is None \
+            else self.held_experts
 
 
 @dataclasses.dataclass(frozen=True)

@@ -156,7 +156,7 @@ def _run_segments(params, cfg: ModelConfig, x, positions, *,
             def body(h, p_layer, seg=seg):
                 y, cache, a = blocks.block_forward(
                     p_layer, h, positions, seg.block, cfg.norm_eps,
-                    use_flash, use_ssd_kernel)
+                    use_flash, use_ssd_kernel, serve=True)
                 ring = blocks.build_ring_cache(cache, positions, seg.block,
                                                cache_len)
                 return y, (ring, a)
@@ -246,8 +246,9 @@ def prefill(params, cfg: ModelConfig, batch: dict, cache_len: int, *,
 
 def _layer_loop(body, x, p_seg, cache_seg, n_layers: int, pooled: bool,
                 unroll: bool = False):
-    """Run ``body(h, p_layer, layer_cache, li) -> (h, new_layer_cache)``
-    over a segment's layers.  Lane-indexed leaves are sliced per layer
+    """Run ``body(h, p_layer, layer_cache, li) -> (h, new_layer_cache,
+    aux)`` over a segment's layers; returns (h, new_cache, aux summed
+    over the layers).  Lane-indexed leaves are sliced per layer
     and restacked; with ``pooled`` the paged pool (``"attn"``) stays
     whole in the loop's carry instead — each layer writes its rows into
     it in place and reads its own layer by index, so no layer's pool is
@@ -260,43 +261,45 @@ def _layer_loop(body, x, p_seg, cache_seg, n_layers: int, pooled: bool,
         c = dict(lane_c)
         if pool is not None:
             c["attn"] = pool
-        h, nc = body(h, p_layer, c, li)
+        h, nc, aux = body(h, p_layer, c, li)
         nc = dict(nc)
-        return h, (nc.pop("attn") if pool is not None else None), nc
+        return h, (nc.pop("attn") if pool is not None else None), (nc, aux)
 
     if unroll:
         outs = []
         for li in range(n_layers):
             def pick(a, li=li):
                 return a[li]
-            x, pool, nc = one(x, pool, jax.tree.map(pick, p_seg), li,
-                              jax.tree.map(pick, laned))
-            outs.append(nc)
-        new = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+            x, pool, out = one(x, pool, jax.tree.map(pick, p_seg), li,
+                               jax.tree.map(pick, laned))
+            outs.append(out)
+        new, aux = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
     else:
         def step(carry, xs):
             h, pool = carry
-            h, pool, nc = one(h, pool, *xs)
-            return (h, pool), nc
+            h, pool, out = one(h, pool, *xs)
+            return (h, pool), out
 
-        (x, pool), new = jax.lax.scan(
+        (x, pool), (new, aux) = jax.lax.scan(
             step, (x, pool),
             (p_seg, jnp.arange(n_layers, dtype=jnp.int32), laned))
     new = dict(new)
     if pool is not None:
         new["attn"] = pool
-    return x, new
+    return x, new, jax.tree.map(lambda a: a.sum(0), aux)
 
 
 def decode_segment(params, cfg: ModelConfig, si: int, x: jax.Array,
                    cache_seg, pos: jax.Array, paged=None, write_mask=None,
                    readout_scope: str | None = None):
     """Run segment `si` for one token.  x (B,1,D) -> (x', new_cache,
-    readout) where readout is None for ramp-less segments and otherwise
-    the full `ramp_readout` pair (logits (B,V), loss proxy (B,)) — the
-    serving engine consumes both, so the head matmul runs exactly once.
-    ``readout_scope`` names the readout's `jax.named_scope` (the serving
-    engine's ``readout<node>``).
+    readout, counts) where readout is None for ramp-less segments and
+    otherwise the full `ramp_readout` pair (logits (B,V), loss proxy
+    (B,)) — the serving engine consumes both, so the head matmul runs
+    exactly once — and counts the expert layer's counters summed over
+    the segment's layers (`moe.EXPERT_COUNTS`; empty for a segment
+    without experts).  ``readout_scope`` names the readout's
+    `jax.named_scope` (the serving engine's ``readout<node>``).
 
     ``paged`` (attention.PagedKV) + ``write_mask`` route the attention
     layers at the paged KV pool; the per-lane page table and write
@@ -304,27 +307,27 @@ def decode_segment(params, cfg: ModelConfig, si: int, x: jax.Array,
     seg = cfg.segments[si]
 
     def body(h, p_layer, c, li):
-        y, nc, _ = blocks.block_decode(p_layer, h, c, pos, seg.block,
-                                       cfg.norm_eps, paged=paged,
-                                       write_mask=write_mask, layer=li)
-        return y, nc
+        return blocks.block_decode(p_layer, h, c, pos, seg.block,
+                                   cfg.norm_eps, paged=paged,
+                                   write_mask=write_mask, layer=li)
 
-    x, new_cache = _layer_loop(body, x, params["segments"][si]["blocks"],
-                               cache_seg, seg.n_layers, paged is not None,
-                               unroll=_DECODE_UNROLL.get())
+    x, new_cache, aux = _layer_loop(
+        body, x, params["segments"][si]["blocks"], cache_seg, seg.n_layers,
+        paged is not None, unroll=_DECODE_UNROLL.get())
     readout = None
     if seg.ramp:
         with (jax.named_scope(readout_scope) if readout_scope
               else contextlib.nullcontext()):
             readout = ramp_readout(params, cfg, x[:, 0, :], segment=si)
-    return x, new_cache, readout
+    return x, new_cache, readout, aux
 
 
 def prefill_chunk_segment(params, cfg: ModelConfig, si: int, x: jax.Array,
                           cache_seg, table: jax.Array, chunk):
     """Run segment ``si`` for one PREFILL CHUNK against the paged pool
-    (DESIGN.md §9).  x (B, C, D) -> (x', new_cache).  Chunks always run
-    full depth (no early exit during prefill: every layer's KV must be
+    (DESIGN.md §9).  x (B, C, D) -> (x', new_cache, the expert
+    counters summed over the layers).  Chunks always run full depth
+    (no early exit during prefill: every layer's KV must be
     complete before decode can share the pages), so there is no ramp
     readout here — the engine reads the final head once, on the chunk
     that finishes the prompt."""
@@ -334,9 +337,10 @@ def prefill_chunk_segment(params, cfg: ModelConfig, si: int, x: jax.Array,
         return blocks.block_prefill_chunk(p_layer, h, c, seg.block,
                                           cfg.norm_eps, table, chunk, li)
 
-    x, new_cache = _layer_loop(body, x, params["segments"][si]["blocks"],
-                               cache_seg, seg.n_layers, True)
-    return constrain_batch(x), new_cache
+    x, new_cache, aux = _layer_loop(body, x,
+                                    params["segments"][si]["blocks"],
+                                    cache_seg, seg.n_layers, True)
+    return constrain_batch(x), new_cache, aux
 
 
 def decode_step(params, cfg: ModelConfig, batch: dict, caches, pos):
@@ -354,7 +358,7 @@ def decode_step(params, cfg: ModelConfig, batch: dict, caches, pos):
     new_caches = []
     node_losses = []
     for si in range(len(cfg.segments)):
-        x, nc, ro = decode_segment(params, cfg, si, x, caches[si], pos)
+        x, nc, ro, _ = decode_segment(params, cfg, si, x, caches[si], pos)
         new_caches.append(nc)
         if ro is not None:
             node_losses.append(ro[1])

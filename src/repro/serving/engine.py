@@ -229,9 +229,13 @@ def make_token_step(params, cfg: ModelConfig, strategies, *,
 
     Returns ``step(tok (B,) i32, caches, pos (B,) i32, occupied (B,)
     bool, sid (B,) i32[, kv][, states][, chunk]) -> (next_tok,
-    new_caches, served_node, seg_batch, seg_policy[, states])`` — seg_*
-    are int32 scalars counting this token's launched segments and
-    per-lane probed segments.  It is a `functools.partial` binding
+    new_caches, served_node, seg_batch, seg_policy[, states][, walk]
+    [, counts])`` — seg_* are int32 scalars counting this token's
+    launched segments and per-lane probed segments.  A model with
+    expert layers also returns ``counts``, the step's expert-layer
+    counters (`models.moe.EXPERT_COUNTS`, int32 scalars summed over the
+    layers that ran, decode and chunk sweep); a model without has no
+    such output.  It is a `functools.partial` binding
     ``params`` as the first argument of the (jitted) program in
     ``.func``, so the weights enter it as arguments; ``params`` may be
     `jax.eval_shape` structs to lower ``.func`` without materializing
@@ -240,8 +244,10 @@ def make_token_step(params, cfg: ModelConfig, strategies, *,
     import contextlib
 
     from repro.models.attention import paged_kernel as _paged_kernel_ctx
+    from repro.models.moe import EXPERT_COUNTS
     from repro.strategy.base import reset_lanes
     strategies = tuple(_check_online(s) for s in strategies)
+    counted = any(seg.block.mlp == "moe" for seg in cfg.segments)
     kernel_ctx = (_paged_kernel_ctx if (paged and paged_kernel)
                   else contextlib.nullcontext)
     if prefill_slots and not paged:
@@ -288,6 +294,11 @@ def make_token_step(params, cfg: ModelConfig, strategies, *,
             best_logits = walk_best
         seg_batch = jnp.zeros((), jnp.int32)
         seg_policy = jnp.zeros((), jnp.int32)
+        counts = {k: jnp.zeros((), jnp.int32) for k in EXPERT_COUNTS} \
+            if counted else {}
+
+        def add(a, b):          # a segment without experts counts none
+            return {k: v + b.get(k, 0) for k, v in a.items()}
         new_caches = list(caches)
         node = node_offset
         # context entered at TRACE time: selects which attention impl
@@ -299,12 +310,14 @@ def make_token_step(params, cfg: ModelConfig, strategies, *,
 
                 @jax.named_scope(f"segment{si}")
                 def run(ops, si=si, node=node):
-                    x, cache, states, act, best = ops
-                    x2, nc, ro = M.decode_segment(
+                    x, cache, states, act, best, cnt = ops
+                    # exited lanes route to no expert, paged or not
+                    x2, nc, ro, seg_cnt = M.decode_segment(
                         params, cfg, si, x, cache, pos,
                         paged=kv if paged else None,
-                        write_mask=act if paged else None,
+                        write_mask=act if paged or counted else None,
                         readout_scope=f"readout{node}")
+                    cnt = add(cnt, seg_cnt)
                     nc = _mask_lane_writes(nc, cache, act, paged=paged)
                     if ro is not None:
                         # ramp readout: serve-from-this-node logits for
@@ -316,10 +329,10 @@ def make_token_step(params, cfg: ModelConfig, strategies, *,
                             states, act, best = fold_readout(
                                 strategies, states, node, *ro, act, sid,
                                 best)
-                    return (x2, nc, states, act, best)
+                    return (x2, nc, states, act, best, cnt)
 
-                ops = (x, caches[si], states, active, best_logits)
-                x, new_caches[si], states, active, best_logits = \
+                ops = (x, caches[si], states, active, best_logits, counts)
+                x, new_caches[si], states, active, best_logits, counts = \
                     jax.lax.cond(active.any(), run, lambda o: o, ops)
                 if seg.ramp:
                     node += 1
@@ -347,24 +360,26 @@ def make_token_step(params, cfg: ModelConfig, strategies, *,
             # page pool, where writes land in disjoint pages.
             with kernel_ctx():
                 @jax.named_scope("chunk_sweep")
-                def run_chunk(cs):
+                def run_chunk(ops):
+                    cs, cnt = ops
                     xc = params["embed"]["table"][chunk.tok]
                     cs = list(cs)
                     for si in range(len(cfg.segments)):
-                        xc, cs[si] = M.prefill_chunk_segment(
+                        xc, cs[si], seg_cnt = M.prefill_chunk_segment(
                             params, cfg, si, xc, cs[si], kv.page_table,
                             chunk)
+                        cnt = add(cnt, seg_cnt)
                     h = xc[jnp.arange(b), chunk.last_idx, :]
                     logits, _ = M.ramp_readout(params, cfg, h)
-                    return (tuple(cs),
+                    return ((tuple(cs), cnt),
                             jnp.argmax(logits, axis=-1).astype(jnp.int32))
 
-                def skip_chunk(cs):
-                    return tuple(cs), jnp.zeros((b,), jnp.int32)
+                def skip_chunk(ops):
+                    return ops, jnp.zeros((b,), jnp.int32)
 
-                chunk_caches, t0 = jax.lax.cond(
+                (chunk_caches, counts), t0 = jax.lax.cond(
                     chunk.active.any(), run_chunk, skip_chunk,
-                    tuple(new_caches))
+                    (tuple(new_caches), counts))
             new_caches = list(chunk_caches)
             # finishing lanes: seed the lane with its first token, just
             # like the stop-the-world admission would have
@@ -379,6 +394,8 @@ def make_token_step(params, cfg: ModelConfig, strategies, *,
             # signal — the lane's strategy wants to probe a node beyond
             # this model's ladder rung
             out = out + ((active, best_logits),)
+        if counted:
+            out = out + (counts,)
         return out
 
     if jit:
@@ -418,7 +435,7 @@ class Engine:
 
         for _ in range(n_tokens):
             tok, caches, served, sb, sp = self._step(tok, caches, pos,
-                                                     occupied, sid)
+                                                     occupied, sid)[:5]
             # the ONLY host sync of the token: emitted tokens, served
             # nodes, and both segment counters in one transfer
             tok_h, served_h, sb_h, sp_h = jax.device_get(

@@ -176,13 +176,17 @@ class StepRecord(NamedTuple):
     decode: np.ndarray      # (k, 5): lane, rid, position, node, token
     chunks: np.ndarray      # (m, 5): lane, rid, start, width, done
     firsts: np.ndarray      # (f, 3): lane, rid, first token
+    # the step's layer counters (expert rows, latent rows read), empty
+    # for a model without expert layers or latent attention
+    counts: dict = {}
 
     @classmethod
-    def of(cls, decode: tuple, chunks: list, firsts: tuple) -> "StepRecord":
-        """From the decode and first-token columns (equal-length arrays)
-        and the chunks' rows."""
+    def of(cls, decode: tuple, chunks: list, firsts: tuple,
+           counts: dict | None = None) -> "StepRecord":
+        """From the decode and first-token columns (equal-length arrays),
+        the chunks' rows and the step's layer counters."""
         return cls(_columns(decode), np.asarray(chunks, np.int64).reshape(
-            -1, 5), _columns(firsts))
+            -1, 5), _columns(firsts), dict(counts or {}))
 
 
 def _columns(cols: tuple) -> np.ndarray:

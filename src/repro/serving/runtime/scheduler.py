@@ -249,13 +249,11 @@ class EngineStepper:
                 raise ValueError("chunked prefill needs --kv paged "
                                  "(chunks commit into the page pool)")
             for seg in cfg.segments:
-                if seg.block.mixer != "attn" \
-                        or seg.block.attn.mla is not None:
+                if seg.block.mixer != "attn":
                     raise ValueError(
-                        "chunked prefill currently supports GQA "
-                        "attention segments only (SSM state is "
-                        "sequential over the prompt; MLA chunking is a "
-                        "ROADMAP item) — drop --prefill-chunk for "
+                        "chunked prefill supports attention segments "
+                        "only (GQA and MLA; SSM state is sequential "
+                        "over the prompt) — drop --prefill-chunk for "
                         f"mixer {seg.block.mixer!r}")
         self.params = params
         self.cfg = cfg
@@ -284,6 +282,14 @@ class EngineStepper:
                                      node_offset=node_offset,
                                      walk_io=walk_io,
                                      resume_walk=resume_walk)
+        # the step's expert counters (a trailing output for models with
+        # expert layers) and the latent-attention layers per segment,
+        # whose reads the step record counts on the host
+        self._counted = any(seg.block.mlp == "moe" for seg in cfg.segments)
+        self._mla_layers = [
+            seg.n_layers if seg.block.attn is not None
+            and seg.block.attn.mla is not None else 0
+            for seg in cfg.segments]
         if kv == "paged":
             from repro.serving.kvpool import KVPool
             lane_pages = -(-self.cache_len // page_size)
@@ -515,6 +521,25 @@ class EngineStepper:
         self.states = tuple(init_lane(s, st, lane)
                             for s, st in zip(self.strategies, self.states))
 
+    def _latent_reads(self, seg_batch: int, pos_h, rows) -> dict:
+        """The latent rows the step's MLA attention read and the live
+        positions among them, from the gather widths: decode gathers
+        every lane's whole page table (T rows) in each MLA layer of the
+        segments it launched, the chunk sweep the page table of each
+        prefilling lane in every MLA layer.  Live are the positions the
+        attending lanes hold: each decoding lane's context (its new
+        token included) and each chunk's end (holes of early-exited
+        tokens counted as live)."""
+        width = (self.pool.lane_pages * self.pool.page_size
+                 if self.pool is not None else self.cache_len)
+        dec = sum(self._mla_layers[:seg_batch])
+        full = sum(self._mla_layers)
+        ctx = int(np.sum(pos_h + 1))
+        ends = sum(int(r[2] + r[3]) for r in rows)
+        return {"latent_rows_read": (self.n_lanes * dec + len(rows) * full)
+                * width,
+                "latent_rows_live": ctx * dec + ends * full}
+
     def _put(self, x, dtype=None) -> jax.Array:
         """One host->device array, counted for the step's span."""
         out = jnp.asarray(x, dtype)
@@ -642,8 +667,13 @@ class EngineStepper:
         ``engine.page_ops``), ``engine.dispatch``, ``pool.commit_prefix``
         and ``engine.sync``; its data holds the counts ``uploads``,
         ``upload_bytes``, ``seg_batch``, ``seg_policy`` (and
-        ``compiles``) and the public per-step record (``record``, a
-        `StepRecord`).
+        ``compiles``), for a model with expert layers the step's
+        ``expert_rows``, ``expert_rows_live`` and ``expert_rows_max``
+        (`models.moe.moe_serve`, fetched with the step's one sync), for
+        a model with latent attention ``latent_rows_read`` and
+        ``latent_rows_live`` (`_latent_reads`), and the public per-step
+        record (``record``, a `StepRecord`, whose ``counts`` repeat
+        these counters).
         """
         with self.spans.span("engine.step") as span:
             return self._fused_step(occupied, sid, walk, span)
@@ -702,6 +732,9 @@ class EngineStepper:
             out = self._step(*args)
         if self.pool is not None:
             self.pool.note_written(decode)
+        counts = {}
+        if self._counted:
+            out, counts = out[:-1], out[-1]
         if self.walk_io:
             tok, self.caches, served, sb, sp, self.states, walk_out = out
         else:
@@ -726,19 +759,22 @@ class EngineStepper:
                     self.pool.commit_prefix(lane, st["prompt"])
         with spans.span("engine.sync"):
             if self.walk_io:
-                tok_h, served_h, sb_h, sp_h, wa_h = jax.device_get(
-                    (tok, served, sb, sp, walk_out[0]))
+                tok_h, served_h, sb_h, sp_h, counts, wa_h = jax.device_get(
+                    (tok, served, sb, sp, counts, walk_out[0]))
             else:
-                tok_h, served_h, sb_h, sp_h = jax.device_get(
-                    (tok, served, sb, sp))
+                tok_h, served_h, sb_h, sp_h, counts = jax.device_get(
+                    (tok, served, sb, sp, counts))
         fin = np.asarray(finished, np.int64)
+        counts = {k: int(v) for k, v in counts.items()}
+        if any(self._mla_layers):
+            counts.update(self._latent_reads(int(sb_h), pos_h, rows))
         span.add(uploads=self._n_up - n_up,
                  upload_bytes=self._up_bytes - up_bytes,
-                 seg_batch=int(sb_h), seg_policy=int(sp_h),
+                 seg_batch=int(sb_h), seg_policy=int(sp_h), **counts,
                  record=StepRecord.of(
                      (lanes, self.lane_rids[lanes], pos_h, served_h[lanes],
                       tok_h[lanes]), rows,
-                     (fin, self.lane_rids[fin], tok_h[fin])))
+                     (fin, self.lane_rids[fin], tok_h[fin]), counts))
         if self.walk_io:
             return (tok_h, served_h, int(sb_h), int(sp_h), decode,
                     (wa_h, walk_out[1]))

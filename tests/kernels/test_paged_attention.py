@@ -252,9 +252,9 @@ def test_paged_kernel_inside_decode_matches_gather_path():
         h = x
         with A.paged_kernel(mode == "kernel"):
             for si in range(len(cfg.segments)):
-                h, _, _ = M.decode_segment(params, cfg, si, h,
-                                           paged_caches[si], pos,
-                                           paged=kv)
+                h, _, _, _ = M.decode_segment(params, cfg, si, h,
+                                              paged_caches[si], pos,
+                                              paged=kv)
         outs[mode], _ = M.ramp_readout(params, cfg, h[:, 0, :])
     np.testing.assert_allclose(np.asarray(outs["kernel"]),
                                np.asarray(outs["gather"]), atol=2e-2,

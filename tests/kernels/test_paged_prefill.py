@@ -180,7 +180,7 @@ def test_prefill_chunk_kernel_matches_gather_in_model():
                     active=jnp.ones((b,), bool))
                 x = params["embed"]["table"][chunk.tok]
                 for si in range(len(cfg.segments)):
-                    x, cs[si] = M.prefill_chunk_segment(
+                    x, cs[si], _ = M.prefill_chunk_segment(
                         params, cfg, si, x, cs[si], jnp.asarray(table),
                         chunk)
                 h_last = x[:, -1, :]

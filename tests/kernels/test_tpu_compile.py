@@ -114,17 +114,10 @@ def _largest(shape_text: str) -> tuple:
                default=())
 
 
-def test_donated_full_width_step_keeps_the_pool_in_place(
-        one_chip, no_persistent_cache):
-    """The full-width paged + chunked step at paper-ee widths (32 lanes,
-    257 pages of 128), donated and compiled for a described v5e: every
-    pool leaf is aliased to its output, no copy, transpose, pad or
-    dynamic-slice the size of one layer's K pool is left anywhere in
-    the program, the arguments do not grow, and its temporaries stay
-    below the pool itself."""
-    cfg = get_config("paper-ee-100m", smoke=False)
-    lanes, n_pages, ps = 32, 257, 128
-    maxp = CACHE_LEN // ps
+def _donated_step(one_chip, cfg, lanes, n_pages, ps, maxp):
+    """The donated full-width paged + chunked serve step of ``cfg``
+    lowered for the described chip from shapes alone; returns (lowered,
+    params, caches)."""
     bf = jnp.bfloat16
 
     def s(shape, dt=jnp.int32):
@@ -132,7 +125,7 @@ def test_donated_full_width_step_keeps_the_pool_in_place(
 
     params = jax.tree.map(lambda a: s(a.shape, a.dtype),
                           abstract(M.model_defs(cfg), bf))
-    casc = strategy.Cascade.uniform(cfg.n_ramps + 1, lam=0.5)
+    casc = strategy.Cascade.uniform(len(cfg.segments), lam=0.5)
     bank = (strategy.make("always_last", casc),)
     caches = jax.tree.map(
         lambda spec: s(*spec),
@@ -152,19 +145,43 @@ def test_donated_full_width_step_keeps_the_pool_in_place(
         step = make_token_step(params, cfg, bank, donate=True,
                                carry_state=True, paged=True,
                                paged_kernel=True, prefill_slots=CHUNK)
-        compiled = step.func.lower(
+        lowered = step.func.lower(
             params, s((lanes,)), caches, s((lanes,)), s((lanes,), bool),
-            s((lanes,)), kv, states, chunk).compile()
+            s((lanes,)), kv, states, chunk)
+    return lowered, params, caches
+
+
+def _aliased(text: str) -> int:
+    aliases = re.search(r"input_output_alias=\{(.*?)\}\s*,?\s*entry_",
+                        text)
+    assert aliases, "no input_output_alias: the pool is not donated"
+    return len(re.findall(r"may-alias|must-alias", aliases.group(1)))
+
+
+def test_donated_full_width_step_keeps_the_pool_in_place(
+        one_chip, no_persistent_cache):
+    """The full-width paged + chunked step at paper-ee widths (32 lanes,
+    257 pages of 128), donated and compiled for a described v5e: every
+    pool leaf is aliased to its output, no copy, transpose, pad or
+    dynamic-slice the size of one layer's K pool is left anywhere in
+    the program, the arguments do not grow, and its temporaries stay
+    below the pool itself.  A model without expert layers returns no
+    counters: tokens, caches, served nodes, the two segment counts and
+    the bank's states."""
+    cfg = get_config("paper-ee-100m", smoke=False)
+    lanes, n_pages, ps = 32, 257, 128
+    lowered, params, caches = _donated_step(one_chip, cfg, lanes, n_pages,
+                                            ps, CACHE_LEN // ps)
+    assert len(lowered.out_info) == 6
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "on_cpu", lambda: False)
+        compiled = lowered.compile()
     text = compiled.as_text()
     ma = compiled.memory_analysis()
     leaves = jax.tree.leaves(caches)
     pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
     assert pool_bytes == 1_214_257_152
-    aliases = re.search(r"input_output_alias=\{(.*?)\}\s*,?\s*entry_",
-                        text)
-    assert aliases, "no input_output_alias: the pool is not donated"
-    assert len(re.findall(r"may-alias|must-alias",
-                          aliases.group(1))) == len(leaves)
+    assert _aliased(text) == len(leaves)
     assert ma.alias_size_in_bytes == pool_bytes
     # the stored layout pads nothing: the arguments (weights, pool,
     # inputs) take no more than with the hd-minor pool before it
@@ -187,6 +204,40 @@ def test_donated_full_width_step_keeps_the_pool_in_place(
     assert not moved, moved
     assert text.count("tpu_custom_call") >= 2
     assert ma.temp_size_in_bytes < pool_bytes, ma.temp_size_in_bytes
+
+
+def test_latent_expert_step_fits_one_chip_with_the_pool_in_place(
+        one_chip, no_persistent_cache):
+    """deepseek-v2-lite-ep8's step at its cell's sizes (16 lanes of
+    8,192 tokens, 1,025 pages of 128), donated and compiled for a
+    described v5e: the latent pool (4.095 GB) aliased leaf by leaf,
+    weights and pool within the chip's 15.75 GB with temporaries under
+    a quarter of the pool, the held experts' grouped products as
+    ragged-dot kernels, and the expert counters among the outputs."""
+    cfg = get_config("deepseek-v2-lite-ep8", smoke=False)
+    lanes, n_pages, ps = 16, 1025, 128
+    lowered, params, caches = _donated_step(one_chip, cfg, lanes, n_pages,
+                                            ps, 8192 // ps)
+    assert len(lowered.out_info) == 7
+    assert sorted(lowered.out_info[-1]) == ["expert_rows",
+                                            "expert_rows_live",
+                                            "expert_rows_max"]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "on_cpu", lambda: False)
+        compiled = lowered.compile()
+    text = compiled.as_text()
+    ma = compiled.memory_analysis()
+    leaves = jax.tree.leaves(caches)
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
+    assert pool_bytes == 4_095_014_400      # 1025 x 128 x 27 x 1156 B
+    assert _aliased(text) == len(leaves)
+    assert ma.alias_size_in_bytes == pool_bytes
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 15.75e9
+    # 0.47 GB: the decode gathers of one layer's pages and the chunk
+    # sweep's expert buffers; one more copy of the latent pool would not
+    # fit under a quarter of it
+    assert ma.temp_size_in_bytes < pool_bytes / 4
+    assert "ragged-dot" in text
 
 
 def test_stepper_programs_donate_every_pool_leaf(one_chip):

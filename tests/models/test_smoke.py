@@ -115,6 +115,8 @@ def test_full_config_instantiates_abstractly(arch):
         "mamba2-130m": 1.3e8, "starcoder2-3b": 3.0e9,
         "musicgen-large": 2.5e9, "phi-3-vision-4.2b": 4.2e9,
         "phi3.5-moe-42b-a6.6b": 42e9, "deepseek-v2-lite-16b": 16e9,
+        # one chip's share: 8 of 64 routed experts per MoE layer
+        "deepseek-v2-lite-ep8": 3.1e9,
         "hymba-1.5b": 1.7e9, "paper-ee-100m": 1.6e8,
     }[arch]
     assert 0.55 * expected < n < 1.6 * expected, (arch, n, expected)
